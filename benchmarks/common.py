@@ -17,7 +17,9 @@ from repro.jpeg.encoder import PAPER_DATASETS, Dataset, build_dataset, \
 # CPU-container scale factor for the paper's corpora (images x resolution).
 # The *structure* (relative sizes, qualities, subsequence sizes) is kept.
 BENCH_SCALE = float(os.environ.get("BENCH_SCALE", "0.02"))
-CACHE_DIR = os.environ.get("BENCH_CACHE", "/tmp/repro_datasets")
+CACHE_DIR = os.environ.get("BENCH_CACHE") or os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".bench_datasets")
 # Decode backend for every suite: "jnp" (reference) or "pallas" (kernels,
 # interpret mode on CPU — see repro.kernels.backend for overrides).
 BENCH_BACKEND = os.environ.get("BENCH_BACKEND", "jnp")

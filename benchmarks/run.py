@@ -129,6 +129,9 @@ def main() -> None:
     from . import backends, breakdown, datasets, quality, serve, skew, \
         stream, subseq_size
     from .common import BENCH_BACKEND, BENCH_SCALE, emit
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     suites = {
         "datasets": datasets,     # Fig. 4/5 + Fig. 8

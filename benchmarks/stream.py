@@ -122,12 +122,10 @@ def run_multihost_rows(n_hosts: int):
     s.bind(("127.0.0.1", 0))
     port = s.getsockname()[1]
     s.close()
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     procs = [subprocess.Popen(
         [sys.executable, "-m", "benchmarks.stream", "--host-worker",
          str(pid), str(n_hosts), str(port)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env)
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
         for pid in range(n_hosts)]
     # one shared wall clock + kill-all on any failure: a dead coordinator
     # must not leave the other workers orphaned in their connect loops
@@ -181,6 +179,11 @@ def main():
         return
     if args.hosts_only and not args.hosts:
         ap.error("--hosts-only requires --hosts N")
+    if args.hosts and os.environ.get("JAX_PLATFORMS") != "cpu":
+        # this process has already imported JAX, and a chip belongs to one
+        # process: the N workers can only share the CPU backend
+        ap.error("--hosts N starts N JAX processes; run it with "
+                 "JAX_PLATFORMS=cpu")
     rows = [] if args.hosts_only else run_rows()
     if args.hosts:
         rows += run_multihost_rows(args.hosts)

@@ -26,9 +26,11 @@ import numpy as np
 
 from repro.core import ParallelDecoder
 from repro.jpeg.encoder import DatasetSpec, build_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--images", type=int, default=32)
     ap.add_argument("--rounds", type=int, default=3)
@@ -37,8 +39,8 @@ def main():
     ap.add_argument("--quality", type=int, default=85)
     ap.add_argument("--chunk-bits", type=int, default=1024)
     ap.add_argument("--backend", choices=("jnp", "pallas"), default="jnp",
-                    help="decode backend (pallas = kernels; compiled on "
-                         "TPU/GPU, interpret mode on CPU)")
+                    help="decode backend (pallas = kernels, interpret "
+                         "mode on CPU; refused on TPU, see docs/KERNELS.md)")
     ap.add_argument("--serve", action="store_true",
                     help="run the continuous-batching async service "
                          "instead of the pre-formed batch modes")

@@ -16,9 +16,11 @@ import numpy as np
 from repro.core import ParallelDecoder
 from repro.jpeg import codec_ref
 from repro.jpeg.encoder import DatasetSpec, build_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     spec = DatasetSpec("quickstart", n_images=16, width=320, height=192,
                        quality=85, subsampling="4:2:0",
                        subsequence_bits=1024)

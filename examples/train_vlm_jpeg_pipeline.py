@@ -28,9 +28,11 @@ from repro.train.checkpoint import latest_step, restore_checkpoint, \
     save_checkpoint
 from repro.train.optimizer import AdamWConfig, init_opt_state, adamw_update
 from repro.train.schedule import warmup_cosine
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--batch", type=int, default=4)

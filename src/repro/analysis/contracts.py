@@ -570,7 +570,7 @@ VERIFIED_SCATTER_MODULES = ("repro/kernels/huffman/ops.py",)
 #: renders this; `python -m repro.analysis kernels` reports coverage).
 KERNEL_CHECK_FAMILIES: Dict[str, str] = {
     "kernel-bounds": (
-        "every in-kernel ref access (get/swap/masked_swap, incl. pl.ds "
+        "every in-kernel ref access (get/swap, incl. pl.ds "
         "dynamic slices) and every unclamped gather index is proven "
         "in-bounds by the IntRange lattice under the documented operand "
         "intervals of KERNEL_CONTRACTS — incl. the fused cells "

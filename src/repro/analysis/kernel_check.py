@@ -8,8 +8,8 @@ the layer where one colliding or out-of-bounds index silently corrupts
 pixels instead of crashing. Three contract families
 (``contracts.KERNEL_CHECK_FAMILIES``):
 
-* **kernel-bounds** — every in-kernel ref access (``get`` / ``swap`` /
-  ``masked_swap``, including ``pl.ds`` dynamic slices) and every
+* **kernel-bounds** — every in-kernel ref access (``get`` / ``swap``,
+  including ``pl.ds`` dynamic slices) and every
   unclamped gather index is proven in-bounds by abstract interpretation
   of the kernel jaxpr over the ``contracts.IntRange`` lattice. Loop
   carries go through a join-widen fixpoint with branch-guard refinement
@@ -61,6 +61,7 @@ import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -86,14 +87,11 @@ class Violation:
 
 
 def _src(eqn) -> str:
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return ""
-        return f"{frame.file_name}:{frame.start_line}"
-    except Exception:
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return ""
+    return f"{frame.file_name}:{frame.start_line}"
 
 
 # ---------------------------------------------------------------------------
@@ -102,15 +100,15 @@ def _src(eqn) -> str:
 
 def _subjaxprs(params):
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jax.extend.core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jax.extend.core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for x in v:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, jax.extend.core.ClosedJaxpr):
                     yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, jax.extend.core.Jaxpr):
                     yield x
 
 
@@ -122,10 +120,10 @@ def iter_eqns(jaxpr):
 
 
 def _is_var(x) -> bool:
-    return isinstance(x, jax.core.Var)
+    return isinstance(x, jax.extend.core.Var)
 
 
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "custom_jvp_call",
+_CALL_PRIMS = ("jit", "closed_call", "core_call", "custom_jvp_call",
                "custom_vjp_call", "custom_vjp_call_jaxpr", "remat", "checkpoint")
 
 #: Value-preserving (content-subset) prims the structural resolver and the
@@ -176,7 +174,7 @@ def _as_where_select(eqn):
             break
     if body is None:
         return None
-    bj = body.jaxpr if isinstance(body, jax.core.ClosedJaxpr) else body
+    bj = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
     if len(bj.outvars) != 1 or bj.constvars \
             or len(bj.invars) != len(eqn.invars):
         return None
@@ -259,7 +257,7 @@ class DefMap:
                         break
                 if body is not None:
                     bj = body.jaxpr if isinstance(
-                        body, jax.core.ClosedJaxpr) else body
+                        body, jax.extend.core.ClosedJaxpr) else body
                     for bi, ai in zip(bj.invars, eqn.invars):
                         self.alias[bi] = ai
                     for ov, bv in zip(eqn.outvars, bj.outvars):
@@ -272,7 +270,7 @@ class DefMap:
                 # the loop body
                 body = eqn.params["jaxpr"]
                 bj = body.jaxpr if isinstance(
-                    body, jax.core.ClosedJaxpr) else body
+                    body, jax.extend.core.ClosedJaxpr) else body
                 nc = eqn.params["num_consts"]
                 for bi, ai in zip(bj.invars[:nc], eqn.invars[:nc]):
                     self.alias[bi] = ai
@@ -518,7 +516,7 @@ class KernelInterp:
             dt = getattr(ov.aval, "dtype", None)
             self.bind(ov, AV(_dtype_range(dt) if dt is not None else None))
 
-    def _p_pjit(self, eqn):
+    def _p_jit(self, eqn):
         synth = _as_where_select(eqn)
         if synth is not None:
             # evaluate jnp.where at the call boundary so the guarded
@@ -538,7 +536,7 @@ class KernelInterp:
             for ov in eqn.outvars:
                 self.bind(ov, AV(None))
             return
-        bj = body.jaxpr if isinstance(body, jax.core.ClosedJaxpr) else body
+        bj = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
         ins = []
         for a in eqn.invars:
             ri = self._ref_of(a)
@@ -807,7 +805,9 @@ class KernelInterp:
         return jax.tree_util.tree_unflatten(tree, list(leaves))
 
     def _indexer_parts(self, eqn):
-        """(ref_atom, indexers, value_atom|None) for get/swap/masked_swap."""
+        """(ref_atom, indexers, value_atom|None) for get/swap — the two
+        primitives ref indexing (``x = ref[idx]``, ``ref[idx] = v``)
+        lowers to."""
         name = eqn.primitive.name
         if name == "get":
             idx = self._unflatten_indexers(eqn.params["tree"], eqn.invars[1:])
@@ -815,14 +815,6 @@ class KernelInterp:
         if name == "swap":
             idx = self._unflatten_indexers(eqn.params["tree"], eqn.invars[2:])
             return eqn.invars[0], idx, eqn.invars[1]
-        if name == "masked_swap":
-            ref, idx, val, _mask = jax.tree_util.tree_unflatten(
-                eqn.params["args_tree"], list(eqn.invars))
-            return ref, idx, val
-        if name == "masked_load":  # pl.load: (ref, indexers, mask, other)
-            ref, idx, _mask, _other = jax.tree_util.tree_unflatten(
-                eqn.params["args_tree"], list(eqn.invars))
-            return ref, idx, None
         raise AssertionError(name)
 
     def _check_dim(self, what: str, dim: int, idx_rng: IntRange,
@@ -910,9 +902,6 @@ class KernelInterp:
         for ov in eqn.outvars:
             self.bind(ov, av)
 
-    _p_masked_swap = _p_swap
-    _p_masked_load = _p_get
-
     def _p_gather(self, eqn):
         """In-kernel jnp advanced indexing — no clip net in Mosaic, so the
         per-component index intervals must be proven."""
@@ -952,7 +941,7 @@ class KernelInterp:
         nc, ncar = p["num_consts"], p["num_carry"]
         length = p["length"]
         body = p["jaxpr"]
-        bj = body.jaxpr if isinstance(body, jax.core.ClosedJaxpr) else body
+        bj = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
 
         const_ins = []
         for a in eqn.invars[:nc]:
@@ -1053,7 +1042,7 @@ def find_pallas_calls(closed_jaxpr) -> List:
 def _index_map_ranges(bm, grid) -> List[IntRange]:
     """Interval-evaluate one BlockSpec index_map jaxpr over the grid."""
     imj = bm.index_map_jaxpr
-    jx = imj.jaxpr if isinstance(imj, jax.core.ClosedJaxpr) else imj
+    jx = imj.jaxpr if isinstance(imj, jax.extend.core.ClosedJaxpr) else imj
     dm = DefMap().build(jx)
     interp = KernelInterp("index_map", {}, [], dm)
     in_avs = [AV(IntRange(0, max(0, g - 1))) for g in grid]
@@ -1066,8 +1055,9 @@ def check_tiling(pc_eqn, cell: str) -> List[Violation]:
     gm = pc_eqn.params["grid_mapping"]
     grid = tuple(gm.grid)
     for bm in gm.block_mappings:
-        shape = tuple(bm.array_shape_dtype.shape)
-        block = tuple(bm.block_shape)
+        shape = tuple(bm.array_aval.shape)
+        block = tuple(d.block_size if isinstance(d, pl.Blocked) else None
+                      for d in bm.block_shape)
         try:
             idx_ranges = _index_map_ranges(bm, grid)
         except Exception as e:  # pragma: no cover - diagnostic path
@@ -1082,8 +1072,8 @@ def check_tiling(pc_eqn, cell: str) -> List[Violation]:
                 f"block rank {len(block)}"))
             continue
         for d, (dim, tile, br) in enumerate(zip(shape, block, idx_ranges)):
-            if not isinstance(tile, int):
-                continue  # squeezed/mapped dims carry no tile here
+            if tile is None:
+                continue  # squeezed/element dims carry no tile here
             try:
                 contracts.check_block_cover(
                     dim, tile, br, f"{bm.origin} dim {d}")
@@ -1097,7 +1087,7 @@ def check_kernel_bounds(pc_eqn, cell: str, contract, params: Dict[str, int]):
     (violations, interp) — the interp is reused by the scatter prover."""
     gm = pc_eqn.params["grid_mapping"]
     kj = pc_eqn.params["jaxpr"]
-    bj = kj.jaxpr if isinstance(kj, jax.core.ClosedJaxpr) else kj
+    bj = kj.jaxpr if isinstance(kj, jax.extend.core.ClosedJaxpr) else kj
     dm = DefMap().build(bj)
     operand_contracts = list(contract.operands) if contract else []
     interp = KernelInterp(cell, params, operand_contracts, dm)
@@ -1210,9 +1200,9 @@ def prove_stream_monotone(interp: KernelInterp, dm: DefMap, bj,
     lane's recorded positions strictly increasing.
     """
     for eqn in iter_eqns(bj):
-        if eqn.primitive.name not in ("swap", "masked_swap"):
+        if eqn.primitive.name != "swap":
             continue
-        ref, val = _store_parts(eqn)
+        ref, val = eqn.invars[0], eqn.invars[1]
         if dm.root(ref, through=()) is not pos_ref_var:
             continue
         sel = dm.rootdef(val)
@@ -1250,7 +1240,7 @@ def self_increment_matches(dm: DefMap, bj, n_atom, run_atom) -> bool:
         if eqn.primitive.name != "scan":
             continue
         body = eqn.params["jaxpr"]
-        sub = body.jaxpr if isinstance(body, jax.core.ClosedJaxpr) else body
+        sub = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
         n_root = dm.root(n_atom)
         for ov in sub.outvars:
             sel = dm.rootdef(ov)
@@ -1350,19 +1340,6 @@ def _real_from_proven_stream(dm: DefMap, real_atom, proven_kernels) -> bool:
         if src_eqn.outvars.index(r) == pos_index:
             return True
     return False
-
-
-# ---------------------------------------------------------------------------
-# Stores (swap/masked_swap) — shared helper for the monotonicity prover
-# ---------------------------------------------------------------------------
-
-def _store_parts(eqn):
-    """(ref_atom, value_atom) of a swap/masked_swap eqn."""
-    if eqn.primitive.name == "swap":
-        return eqn.invars[0], eqn.invars[1]
-    ref, _idx, val, _mask = jax.tree_util.tree_unflatten(
-        eqn.params["args_tree"], list(eqn.invars))
-    return ref, val
 
 
 # ---------------------------------------------------------------------------
@@ -1699,7 +1676,7 @@ def run_self_test(verbose: bool = False) -> List[str]:
     # 1. off-by-one pl.ds: rows [1, 8] into an 8-row operand
     def bad_kernel(x_ref, o_ref):
         def body(i, acc):
-            v = pl.load(x_ref, (pl.ds(i + 1, 1), slice(None)))
+            v = x_ref[pl.ds(i + 1, 1), :]
             return acc + jnp.sum(v)
         o_ref[0, 0] = jax.lax.fori_loop(0, 8, body, jnp.float32(0.0))
 
@@ -1765,14 +1742,14 @@ def run_self_test(verbose: bool = False) -> List[str]:
         in_specs=[
             pl.BlockSpec((tile_m * upm, 64), lambda i: (i, 0)),
             pl.BlockSpec((tile_m * upm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 128, 128), lambda i: (0, 0, 0)),
+            pl.BlockSpec((1, 64, 64), lambda i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_m, 3, 16, 16), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((10, 3, 16, 16), jnp.float32),
         interpret=True)
     closed = jax.make_jaxpr(fn)(
         _sds((10 * upm, 64), jnp.float32), _sds((10 * upm, 1), jnp.int32),
-        _sds((1, 128, 128), jnp.float32))
+        _sds((1, 64, 64), jnp.float32))
     vs = _check_cell("self-test:fused-tile-misalign", closed, None, {})
     if not any(v.family == "kernel-tiling" for v in vs):
         failures.append("seeded fused-cell tile misalignment not caught "

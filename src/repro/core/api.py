@@ -54,7 +54,8 @@ import jax.numpy as jnp
 from . import decode as D
 from ..dist import sharding as S
 from ..kernels.autotune import TileConfig, autotune_enabled, autotune_tiles
-from ..kernels.backend import (check_backend, check_fuse, resolve_backend,
+from ..kernels.backend import (check_backend, check_fuse,
+                               check_pallas_compiles, resolve_backend,
                                resolve_fuse)
 from ..jpeg.format import parse_jpeg, segment_byte_bounds, unstuff_scan
 from .bitstream import (BatchPlan, BatchValidation, LADDER_STEP, PlanShape,
@@ -595,6 +596,8 @@ class ParallelDecoder:
                  tiles: Optional[TileConfig] = None):
         assert sync in ("jacobi", "faithful", "sequential", "specmap")
         check_backend(backend)
+        if backend == "pallas" and jax.default_backend() == "tpu":
+            check_pallas_compiles(jax.devices()[0])
         self.sync = sync
         self.backend = backend
         self.interpret = interpret
@@ -834,21 +837,26 @@ class ParallelDecoder:
         the same devices: every chip becomes a lane worker, and the
         partial replication a 2-D mesh would induce — which the CPU SPMD
         partitioner has been observed to mis-compile for this scatter-
-        heavy program — never arises. Caller-supplied ``rules`` name the
+        heavy program — never arises. Any mesh, whatever its axis types,
+        is accepted. Caller-supplied ``rules`` name the
         axes of ``mesh`` itself and therefore require a 1-D mesh: any
         multi-axis mesh would reintroduce that partial replication, so
         the combination is rejected rather than silently re-mapped.
         """
-        if rules is None:
-            if len(mesh.axis_names) > 1:
-                mesh = jax.sharding.Mesh(mesh.devices.reshape(-1), ("data",))
-            rules = _decode_rules(mesh)
-        elif len(mesh.axis_names) > 1:
+        if rules is not None and len(mesh.axis_names) > 1:
             raise ValueError(
                 "decode_on(rules=...) requires a 1-D mesh; flatten the mesh "
                 "(e.g. Mesh(mesh.devices.reshape(-1), ('data',))) or omit "
                 "rules to let the decoder flatten it"
             )
+        # the lane mesh is rebuilt with an Auto axis: jax.make_mesh now
+        # defaults to Explicit axes, which the GSPMD sharding constraints
+        # of the decode program do not accept
+        axis = mesh.axis_names[0] if rules is not None else "data"
+        mesh = jax.sharding.Mesh(mesh.devices.reshape(-1), (axis,),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
+        if rules is None:
+            rules = _decode_rules(mesh)
         with mesh, S.logical_rules(rules):
             return self.decode(emit=emit)
 

@@ -116,6 +116,17 @@ class Dataset:
         return self.compressed_mb * 1000 / max(1, len(self.jpeg_bytes))
 
 
+def _encoder_digest() -> str:
+    """Digest of the source that generates a corpus (this module and the
+    reference encoder): a cached corpus is reused only while it matches."""
+    from . import codec_ref
+    h = hashlib.sha1()
+    for mod in (codec_ref.__file__, __file__):
+        with open(mod, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def build_dataset(
     spec: DatasetSpec,
     seed: int = 0,
@@ -125,9 +136,8 @@ def build_dataset(
     """Encode a full synthetic dataset; disk-cached by content hash."""
     key = None
     if cache_dir:
-        h = hashlib.sha1(
-            repr((dataclasses.astuple(spec), seed, keep_truth, 3)).encode()
-        ).hexdigest()[:16]
+        h = hashlib.sha1(repr((dataclasses.astuple(spec), seed, keep_truth,
+                               _encoder_digest())).encode()).hexdigest()[:16]
         key = os.path.join(cache_dir, f"{spec.name}_{h}.pkl")
         if os.path.exists(key):
             with open(key, "rb") as f:

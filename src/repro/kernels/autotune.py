@@ -10,11 +10,11 @@ a small measured search over a fixed candidate set, keyed by
 ``(PlanShape, backend, fuse, device_kind)``:
 
 * resolution order: ``REPRO_PALLAS_TILES`` env override (parsed and
-  validated loudly) > in-memory cache > persistent on-disk table
-  (``REPRO_PALLAS_TILE_TABLE``, default ``~/.cache/repro/pallas_tiles
-  .json``) > measured search (only when a ``measure`` callable is
-  supplied — the decoder wires one up under ``REPRO_PALLAS_AUTOTUNE=1``)
-  > the built-in defaults.
+  validated loudly) > in-memory cache > persistent on-disk table (only
+  when ``REPRO_PALLAS_TILE_TABLE`` names one: a table the caller did not
+  name would change which program compiles) > measured search (only when
+  a ``measure`` callable is supplied — the decoder wires one up under
+  ``REPRO_PALLAS_AUTOTUNE=1``) > the built-in defaults.
 
 * the chosen :class:`TileConfig` is **part of the compiled-program cache
   key** (``core/api.decode_program``), so tuning happens at most once per
@@ -92,15 +92,6 @@ def check_tile(name: str, value: int) -> int:
             f"tile {name}={value} must be a multiple of 8 (sublane "
             f"alignment; a non-multiple would leave the padded lane "
             f"capacity non-divisible by the tile)")
-    if name == "unit_tile" and value % 2:
-        raise ValueError(
-            f"tile unit_tile={value} must be even (the IDCT kernel "
-            f"pairs adjacent units into 128-lane rows)")
-    if name == "mcu_tile" and value % 2:
-        raise ValueError(
-            f"tile mcu_tile={value} must be even (the fused pixel "
-            f"kernel pairs units; an odd units-per-MCU layout would "
-            f"break the pairing on odd MCU tiles)")
     return value
 
 
@@ -151,13 +142,9 @@ _TUNED: Dict[str, TileConfig] = {}
 
 
 def device_kind() -> str:
-    try:
-        import jax
-        return jax.devices()[0].device_kind.replace(" ", "-")
-    except (ImportError, RuntimeError, IndexError):
-        # no jax / no initialized backend: tune keys degrade to a shared
-        # "unknown" device bucket rather than failing the decode path
-        return "unknown"
+    """The tuning key's hardware; a backend that fails to start raises."""
+    import jax
+    return jax.devices()[0].device_kind.replace(" ", "-")
 
 
 def tune_key(shape, backend: str, fuse: str,
@@ -169,12 +156,10 @@ def tune_key(shape, backend: str, fuse: str,
     return f"{label}|{backend}|{fuse}|{kind or device_kind()}"
 
 
-def table_path() -> str:
-    env = os.environ.get(TABLE_ENV)
-    if env:
-        return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "pallas_tiles.json")
+def table_path() -> Optional[str]:
+    """The persistent tile table ``REPRO_PALLAS_TILE_TABLE`` names, or
+    None: no table is read or written unless one is named."""
+    return os.environ.get(TABLE_ENV) or None
 
 
 def _load_table(path: str) -> Dict[str, Dict]:
@@ -220,7 +205,7 @@ def autotune_tiles(shape, backend: str, fuse: str, *,
     ``measure(cfg) -> seconds`` runs one warm decode step under ``cfg``;
     when supplied, the search measures every :func:`candidate_configs`
     point once, memoizes the winner in-process, and persists it to the
-    on-disk table so future processes skip the search entirely. Without
+    named on-disk table (if any) so future processes skip the search. Without
     ``measure`` the call is pure lookup (override > caches > defaults) —
     it never traces, so resolving tiles for a warm bucket is free.
     """
@@ -234,7 +219,7 @@ def autotune_tiles(shape, backend: str, fuse: str, *,
     if hit is not None:
         return hit
     path = table_path()
-    row = _load_table(path).get(key)
+    row = _load_table(path).get(key) if path else None
     if row is not None:
         try:
             cfg = TileConfig(**{k: check_tile(k, int(v))
@@ -252,5 +237,6 @@ def autotune_tiles(shape, backend: str, fuse: str, *,
         if t < best_t:
             best, best_t = cand, t
     _TUNED[key] = best
-    _store_entry(path, key, best)
+    if path:
+        _store_entry(path, key, best)
     return best

@@ -27,12 +27,15 @@ decoder API (``core/api.py``):
   interpreter: compiled Pallas never ran off-CPU. Resolution order:
 
     1. an explicit ``interpret=`` argument (tests force interpret mode),
-    2. the ``REPRO_PALLAS_INTERPRET`` env var (``"1"``/``"0"``),
+    2. the ``REPRO_PALLAS_INTERPRET`` env var (``"1"``/``"0"``); ``"1"``
+       on an accelerator raises, so a stray variable can never run the
+       kernels in the interpreter on a chip,
     3. platform default: interpret on CPU (the only backend the
        interpreter-free path cannot target), compiled on TPU/GPU.
 """
 from __future__ import annotations
 
+import functools
 import os
 import warnings
 from typing import Optional
@@ -116,5 +119,34 @@ def default_interpret(interpret: Optional[bool] = None) -> bool:
             raise ValueError(
                 f"{INTERPRET_ENV} must be '0' or '1', got {env!r}"
             )
+        platform = jax.default_backend()
+        if env == "1" and platform != "cpu":
+            raise ValueError(
+                f"{INTERPRET_ENV}=1 would run the Pallas kernels in the "
+                f"interpreter on {platform!r}; unset it on an accelerator"
+            )
         return env == "1"
     return jax.default_backend() == "cpu"
+
+
+class KernelRefusedError(RuntimeError):
+    """The target chip's compiler refuses a kernel the Pallas backend
+    needs; raised instead of falling back to jnp or the interpreter."""
+
+
+@functools.lru_cache(maxsize=None)
+def _huffman_refusal(device) -> Optional[str]:
+    from .huffman.huffman import compile_refusal
+    return compile_refusal(device)
+
+
+def check_pallas_compiles(device) -> None:
+    """Raise :class:`KernelRefusedError` when ``device``'s compiler refuses
+    the Huffman symbol-step kernel that every Pallas decode runs."""
+    refusal = _huffman_refusal(device)
+    if refusal is not None:
+        raise KernelRefusedError(
+            f"backend=\"pallas\" cannot run on {device.device_kind}: Mosaic "
+            f"refuses the Huffman symbol step's per-lane word gather and "
+            f"flat LUT gather (kernels/huffman/huffman.py): {refusal}. "
+            f"Use backend=\"jnp\".")

@@ -6,8 +6,9 @@ the sampling factors, so upsampling is free VMEM addressing plus an
 in-register repeat, never an HBM round-trip (the paper's trailing stage does
 this as separate kernels; fusing removes two full-plane HBM passes).
 
-Block shapes (4:2:0): y (8, 256), cb/cr (4, 128) -> out (3, 8, 256).
-VMEM per step ~ 24 KiB.
+The luma tile is ``8*fv`` rows, so every chroma block keeps the 8
+sublanes the TPU block rule asks for. Block shapes (4:2:0): y (16, 256),
+cb/cr (8, 128) -> out (3, 16, 256). VMEM per step ~ 48 KiB.
 """
 from __future__ import annotations
 
@@ -44,7 +45,7 @@ def _kernel(y_ref, cb_ref, cr_ref, o_ref, *, fh: int, fv: int):
 
 @functools.partial(jax.jit, static_argnames=("fh", "fv", "interpret"))
 def upsample_color(
-    y: jnp.ndarray,   # (B, H, W) float32, H % (8*fv) == 0, W % (256*fh) == 0 after pad
+    y: jnp.ndarray,   # (B, H, W) float32, padded here to (8*fv, 256) tiles
     cb: jnp.ndarray,  # (B, H/fv, W/fh)
     cr: jnp.ndarray,
     fh: int = 1,
@@ -52,16 +53,17 @@ def upsample_color(
     interpret: bool = None,
 ) -> jnp.ndarray:
     interpret = default_interpret(interpret)
-    if fv <= 0 or fh <= 0 or TILE_H % fv or TILE_W % fh:
-        # e.g. fv=3: the chroma BlockSpec (TILE_H//fv, TILE_W//fh) would
-        # floor to 2 rows and silently skip every third chroma row — the
-        # kernel-tiling contract's runtime twin (analysis/kernel_check.py)
+    if fv <= 0 or fh <= 0 or TILE_W % fh:
+        # e.g. fh=3: the chroma BlockSpec width TILE_W//fh would floor and
+        # silently skip chroma columns — the kernel-tiling contract's
+        # runtime twin (analysis/kernel_check.py)
         raise ValueError(
-            f"sampling factors (fh={fh}, fv={fv}) must divide the luma "
-            f"tile ({TILE_H}x{TILE_W}); a non-dividing factor truncates "
-            f"the chroma BlockSpec ({TILE_H}//{fv} x {TILE_W}//{fh})")
+            f"sampling factors (fh={fh}, fv={fv}) must be positive and fh "
+            f"must divide the luma tile width {TILE_W}; a non-dividing "
+            f"factor truncates the chroma BlockSpec ({TILE_W}//{fh})")
+    tile_h = TILE_H * fv
     b, h, w = y.shape
-    ph = (-h) % TILE_H
+    ph = (-h) % tile_h
     pw = (-w) % TILE_W
     yp = jnp.pad(y, ((0, 0), (0, ph), (0, pw)))
     pch = (yp.shape[1] // fv) - cb.shape[1]
@@ -70,16 +72,16 @@ def upsample_color(
     crp = jnp.pad(cr, ((0, 0), (0, pch), (0, pcw)))
 
     hh, ww = yp.shape[1], yp.shape[2]
-    grid = (b, hh // TILE_H, ww // TILE_W)
+    grid = (b, hh // tile_h, ww // TILE_W)
     out = pl.pallas_call(
         functools.partial(_kernel, fh=fh, fv=fv),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, TILE_H, TILE_W), lambda i, j, k: (i, j, k)),
-            pl.BlockSpec((1, TILE_H // fv, TILE_W // fh), lambda i, j, k: (i, j, k)),
-            pl.BlockSpec((1, TILE_H // fv, TILE_W // fh), lambda i, j, k: (i, j, k)),
+            pl.BlockSpec((1, tile_h, TILE_W), lambda i, j, k: (i, j, k)),
+            pl.BlockSpec((1, TILE_H, TILE_W // fh), lambda i, j, k: (i, j, k)),
+            pl.BlockSpec((1, TILE_H, TILE_W // fh), lambda i, j, k: (i, j, k)),
         ],
-        out_specs=pl.BlockSpec((1, 3, TILE_H, TILE_W), lambda i, j, k: (i, 0, j, k)),
+        out_specs=pl.BlockSpec((1, 3, tile_h, TILE_W), lambda i, j, k: (i, 0, j, k)),
         out_shape=jax.ShapeDtypeStruct((b, 3, hh, ww), jnp.float32),
         interpret=interpret,
     )(yp, cbp, crp)

@@ -11,10 +11,10 @@ of the unfused chain (``idct`` output -> ``assemble_planes`` ->
 ``upsample_color`` input) disappear.
 
 Bit-parity with the unfused path is by construction, not by tolerance:
-the IDCT block is the identical op sequence of ``kernels/idct/idct.py``
-(same unit pairing, same ``dot_general`` dimension numbers with K=128 —
-so per-row f32 reductions match regardless of tile height — same
-mask-select, same ``clip(round(acc + 128))``), and the color block is
+the IDCT block is the shared ``kernels/idct/idct.idct_tile`` (one
+K=64 ``dot_general`` per quantization table, per-row f32 reductions
+independent of tile height, mask-select, ``clip(round(acc + 128))``),
+and the color block is
 the identical elementwise arithmetic of ``core/decode.upsample_color``
 (replicate-upsample, BT.601 constants in the same order, final
 ``clip(round(.))``). The per-MCU plane slices are static: a uniform
@@ -24,8 +24,13 @@ major) is a trace-time constant.
 VMEM per grid step (4:2:0, tile_m=64, nq=2, f32):
   x tile  (384, 64)    =  96 KiB
   rows    (384, 1)     = 1.5 KiB
-  M2      (2,128,128)  = 128 KiB
-  out     (64,3,16,16) =  192 KiB          total ~0.4 MiB << 16 MiB VMEM.
+  M       (2, 64, 64)  =  32 KiB
+  out     (64,3,16,16) =  192 KiB          total ~0.3 MiB << 16 MiB VMEM.
+
+On v5e Mosaic refuses the per-MCU plane assembly below (the
+``(tile_m, v*h, 64) -> (tile_m, v, h, 8, 8)`` lane split is an
+"unsupported shape cast"); the kernel runs in interpret mode only until
+the assembly is redesigned.
 """
 from __future__ import annotations
 
@@ -38,12 +43,13 @@ from jax.experimental import pallas as pl
 
 from ..autotune import DEFAULT_TILES
 from ..backend import default_interpret
+from ..idct.idct import idct_tile
 
 
 def _pixels_kernel(
     x_ref,     # (tile_m * upm, 64) f32 zig-zag coefficients, MCU-major
     rows_ref,  # (tile_m * upm, 1) i32 folded-matrix row per unit
-    m2_ref,    # (nq, 128, 128) f32 block-diagonalized folded operators
+    m_ref,     # (nq, 64, 64) f32 folded operators
     o_ref,     # (tile_m, 3, 8*v_max, 8*h_max) f32 RGB (clipped, rounded)
     *,
     nq: int,
@@ -55,20 +61,7 @@ def _pixels_kernel(
     tile_m: int,
 ):
     # -- IDCT: the exact op sequence of idct.idct._kernel -----------------
-    x = x_ref[...]
-    t = x.shape[0]
-    x2 = x.reshape(t // 2, 128)
-    acc = jnp.zeros_like(x2)
-    for q in range(nq):
-        y2 = jax.lax.dot_general(
-            x2, m2_ref[q],
-            dimension_numbers=(((1,), (1,)), ((), ())),  # x2 @ M2[q].T
-            preferred_element_type=jnp.float32,
-        )
-        mask2 = (rows_ref[...] == q).reshape(t // 2, 2)
-        mask2 = jnp.repeat(mask2, 64, axis=1)
-        acc = jnp.where(mask2, y2, acc)
-    pix = jnp.clip(jnp.round(acc + 128.0), 0.0, 255.0).reshape(t, 64)
+    pix = idct_tile(x_ref[...], rows_ref[...], m_ref, nq)
 
     # -- per-MCU plane assembly + replicate upsample ----------------------
     # Units within an MCU are component-blocked: comp 0's v*h units (row-
@@ -99,21 +92,18 @@ def _pixels_kernel(
 
 
 def _tile_for_mcus(n: int, cap: int) -> int:
-    """MCU tile: cap for big batches, an even cover for small ones (the
-    unit-pairing reshape needs an even unit count per grid step when upm
-    is odd, e.g. 4:4:4)."""
+    """MCU tile: cap for big batches, an even cover for small ones."""
     return min(cap, -(-n // 2) * 2)
 
 
 def _check_mcu_tiling(n: int, pad: int, tile: int, upm: int) -> None:
     """Runtime twin of the kernel-tiling contract for the fused pixel
     grid (see huffman._check_lane_tiling for the lane-axis analogue)."""
-    if tile <= 0 or (n + pad) % tile or (tile * upm) % 2:
+    if tile <= 0 or (n + pad) % tile:
         raise ValueError(
             f"fused pixel tiling broken: {n} MCUs + pad {pad} vs MCU "
             f"tile {tile} (upm={upm}); the tile must divide the padded "
-            f"MCU count and tile*upm must be even for unit pairing — "
-            f"pick an even tile (see autotune.check_tile)")
+            f"MCU count (see autotune.check_tile)")
 
 
 @functools.partial(
@@ -152,8 +142,6 @@ def fused_pixels_pallas(
     _check_mcu_tiling(n_mcus, pad, tile_m, upm)
 
     nq = m_matrices.shape[0]
-    eye2 = jnp.eye(2, dtype=m_matrices.dtype)
-    m2 = jnp.einsum("ab,qij->qaibj", eye2, m_matrices).reshape(nq, 128, 128)
 
     x = jnp.pad(coeffs.astype(jnp.float32), ((0, pad * upm), (0, 0)))
     rows = jnp.pad(unit_mrow.astype(jnp.int32), (0, pad * upm))[:, None]
@@ -170,12 +158,12 @@ def fused_pixels_pallas(
         in_specs=[
             pl.BlockSpec((tu, 64), lambda i: (i, 0)),
             pl.BlockSpec((tu, 1), lambda i: (i, 0)),
-            pl.BlockSpec((nq, 128, 128), lambda i: (0, 0, 0)),
+            pl.BlockSpec((nq, 64, 64), lambda i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_m, 3, mcu_h, mcu_w),
                                lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
             (n_mcus + pad, 3, mcu_h, mcu_w), jnp.float32),
         interpret=interpret,
-    )(x, rows, m2)
+    )(x, rows, m_matrices.astype(jnp.float32))
     return out[:n_mcus]

@@ -19,7 +19,7 @@ intra-kernel race to prove beyond what the stream form already proves
 enforces the reduction by only accepting the fused-store cell when the
 stream cell's monotonicity proof passed in the same run. The store index
 is clamped to the buffer (``jnp.clip``) so the bounds family can verify
-every ``pl.store`` from the interval lattice alone; clamped-but-masked
+every in-kernel store from the interval lattice alone; clamped-but-masked
 lanes write nothing (the read-modify-write keeps the old value).
 
 The fused store keeps the whole coefficient buffer resident per grid
@@ -89,9 +89,9 @@ def _store_kernel(
         idx = jnp.clip(tgt, 0, n_coef - 1)
 
         def lane_body(l, _):
-            cur = pl.load(coef_ref, (pl.ds(idx[l], 1),))
+            cur = coef_ref[pl.ds(idx[l], 1)]
             new = jnp.where(ok[l], coef[l], cur[0])
-            pl.store(coef_ref, (pl.ds(idx[l], 1),), new[None])
+            coef_ref[pl.ds(idx[l], 1)] = new[None]
             return _
 
         jax.lax.fori_loop(0, tile, lane_body, 0)

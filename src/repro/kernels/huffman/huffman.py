@@ -32,15 +32,22 @@ The write kernel adds 2*(TILE, s_max) i32 output tiles, so it runs with
 a smaller lane tile (WRITE_TILE_C) to stay inside the same budget.
 
 TPU lowering note: the LUT lookup and the per-lane word fetch are dynamic
-VMEM gathers (Mosaic `vector.gather`); supported on v4+/v5 — on older
-toolchains the word fetch can fall back to a masked O(W) reduction. The
-kernel bodies are validated in interpret mode against the pure-jnp decoder
-(itself bit-exact vs the sequential oracle). Backend selection (compiled
-vs interpret) lives in ``repro.kernels.backend``.
+VMEM gathers, and Mosaic (JAX 0.9, v5e) refuses both: its
+``_gather_lowering_rule`` asserts ``indices_aval.shape == in_aval.shape +
+(1,)``, which neither the 2-D per-lane word gather ``words[lanes, w]`` nor
+the flat LUT gather ``luts_ref[row * 65536 + win16]`` meets. So both
+kernels here (and ``kernels/fused/store.py``, which shares the symbol
+step) run in interpret mode only, and ``backend="pallas"`` is refused on
+a TPU (:func:`compile_refusal`, ``kernels.backend.check_pallas_compiles``)
+until the symbol step is redesigned. The kernel bodies are validated in
+interpret mode against the pure-jnp decoder (itself bit-exact vs the
+sequential oracle).
 """
 from __future__ import annotations
 
 import functools
+import traceback
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -161,8 +168,8 @@ def _write_kernel(
         n = carry[3]
         rec = active & ~invalid
         pos = jnp.where(rec, n + run_eff, -1)
-        pl.store(pos_ref, (slice(None), pl.ds(i, 1)), pos[:, None])
-        pl.store(val_ref, (slice(None), pl.ds(i, 1)), coef[:, None])
+        pos_ref[:, pl.ds(i, 1)] = pos[:, None]
+        val_ref[:, pl.ds(i, 1)] = coef[:, None]
         return nxt
 
     p, u, z, n = jax.lax.fori_loop(0, s_max, body, carry0)
@@ -360,3 +367,32 @@ def decode_coeffs_pallas(
         pos[:c],
         val[:c],
     )
+
+
+def compile_refusal(device) -> Optional[str]:
+    """Compile :func:`decode_exits_pallas` for ``device`` (attached, or
+    described by ``jax.experimental.topologies``) at one 8-lane tile.
+
+    Returns the compiler's refusal — exception type, the frame that
+    raised and its message — or None when the kernel compiles. The
+    refusal does not depend on the tile, so the smallest one is checked.
+    """
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(device)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    c = 8
+    args = ([sds((64,), jnp.uint32), sds((1, 65536), jnp.int32),
+             sds((c, 6, 2), jnp.int32)] + [sds((c,), jnp.int32)] * 7)
+    try:
+        decode_exits_pallas.lower(*args, s_max=4, min_code_bits=2,
+                                  chunk_words=32, interpret=False).compile()
+    # any refusal is the answer, reported whole to the caller
+    except Exception as e:  # repro: allow[swallowed-format-error]
+        frame = traceback.extract_tb(e.__traceback__)[-1]
+        first = (str(e).strip().splitlines() or [""])[0]
+        return (f"{type(e).__name__} in {frame.name} ({frame.line}) "
+                f"{first}").strip()
+    return None

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -43,15 +44,6 @@ from ...core.state import DecodeState
 from ..backend import default_interpret
 from .huffman import decode_coeffs_pallas, decode_exits_pallas
 from .ref import decode_exits_ref  # noqa: F401  (re-exported oracle)
-
-
-def _shard_map():
-    try:  # jax >= 0.5
-        from jax import shard_map
-        return shard_map, {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map, {"check_rep": False}
 
 
 def _lane_meta(dev: Dict[str, jnp.ndarray], idx) -> Tuple[jnp.ndarray, ...]:
@@ -97,13 +89,12 @@ def _run(fn, dev, entry, idx, kw, mesh, lane_axis, out_specs_fn):
     lane_specs = tuple(
         P(lane_axis, *([None] * (a.ndim - 1))) for a in padded
     )
-    sm, sm_kw = _shard_map()
-    f = sm(
+    f = jax.shard_map(
         lambda words, luts, *la: fn(words, luts, *la, **kw),
         mesh=mesh,
         in_specs=(P(), P()) + lane_specs,
         out_specs=out_specs_fn(lane_axis),
-        **sm_kw,
+        check_vma=False,
     )
     return f(dev["words"], dev["luts"], *padded), c
 

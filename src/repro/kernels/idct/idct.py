@@ -3,16 +3,17 @@
 The paper implements this stage as one CUDA kernel with a thread per 8x8
 data unit. The TPU-native formulation (DESIGN.md §3) folds the whole stage
 into a single matmul: ``pixels = M @ zigzag_coeffs`` with
-``M = (C^T (x) C^T) diag(q) P``. To feed the 128x128 MXU at full tile width
-we additionally *pair* adjacent units: two 64-vectors concatenate to a
-128-lane row and M is block-diagonalized to (128, 128). Quantization-table
-selection is a per-unit mask over the (tiny) set of distinct tables.
+``M = (C^T (x) C^T) diag(q) P``, one (T, 64) x (64, 64) product per
+distinct quantization table, selected per unit by a mask — the same op
+sequence as the jnp reference ``core/decode.idct_units_folded``. (Pairing
+two units into a 128-lane row would fill the MXU width, but Mosaic
+refuses the ``(T, 64) -> (T/2, 128)`` reshape on v5e.)
 
 VMEM budget per grid step (TILE_U=512, NQ=2, f32):
   x tile  (512, 64)   = 128 KiB
   rows    (512, 1)    =   2 KiB
-  M2      (2,128,128) = 128 KiB
-  out     (512, 64)   = 128 KiB            total ~0.4 MiB << 16 MiB VMEM.
+  M       (2, 64, 64) =  32 KiB
+  out     (512, 64)   = 128 KiB            total ~0.3 MiB << 16 MiB VMEM.
 """
 from __future__ import annotations
 
@@ -20,29 +21,32 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from ..backend import default_interpret
 
-TILE_U = 512  # units per grid step; multiple of 8*2 (sublane x pairing)
+TILE_U = 512  # units per grid step; multiple of 8 (sublanes)
 
 
-def _kernel(x_ref, rows_ref, m2_ref, o_ref, *, nq: int):
-    x = x_ref[...]                       # (T, 64) f32
-    t = x.shape[0]
-    x2 = x.reshape(t // 2, 128)          # pair units -> full MXU tile width
-    acc = jnp.zeros_like(x2)
+def idct_tile(x, rows, m_ref, nq: int):
+    """(T, 64) f32 zig-zag coefficients -> (T, 64) clipped, rounded pixels.
+
+    Shared with the fused pixel kernel so both stay the op sequence of
+    ``core/decode.idct_units_folded``.
+    """
+    acc = jnp.zeros_like(x)
     for q in range(nq):                  # nq is tiny (distinct quant tables)
-        y2 = jax.lax.dot_general(
-            x2, m2_ref[q],
-            dimension_numbers=(((1,), (1,)), ((), ())),  # x2 @ M2[q].T
+        y = jax.lax.dot_general(
+            x, m_ref[q],
+            dimension_numbers=(((1,), (1,)), ((), ())),  # x @ M[q].T
             preferred_element_type=jnp.float32,
         )
-        mask2 = (rows_ref[...] == q).reshape(t // 2, 2)
-        mask2 = jnp.repeat(mask2, 64, axis=1)            # per-unit -> per-lane
-        acc = jnp.where(mask2, y2, acc)
-    o_ref[...] = jnp.clip(jnp.round(acc + 128.0), 0.0, 255.0).reshape(t, 64)
+        acc = jnp.where(rows == q, y, acc)   # (T, 1) mask per unit
+    return jnp.clip(jnp.round(acc + 128.0), 0.0, 255.0)
+
+
+def _kernel(x_ref, rows_ref, m_ref, o_ref, *, nq: int):
+    o_ref[...] = idct_tile(x_ref[...], rows_ref[...], m_ref, nq)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -56,16 +60,14 @@ def fused_idct(
     interpret = default_interpret(interpret)
     tile_u = tile if tile is not None else TILE_U
     u, width = coeffs.shape
-    if width != 64 or tile_u % 2 or tile_u <= 0:
-        # the unit-pairing reshape below needs 64 lanes per unit and an
-        # even tile — kernel-tiling contract twin (analysis/kernel_check)
+    if width != 64 or tile_u % 8 or tile_u <= 0:
+        # 64 lanes per unit and a sublane-aligned tile — kernel-tiling
+        # contract twin (analysis/kernel_check)
         raise ValueError(
-            f"fused_idct needs (U, 64) coefficients and a positive even "
-            f"unit tile; got width {width}, tile {tile_u}")
+            f"fused_idct needs (U, 64) coefficients and a positive unit "
+            f"tile that is a multiple of 8; got width {width}, "
+            f"tile {tile_u}")
     nq = m_matrices.shape[0]
-    # block-diagonalize each M for the unit-pairing trick
-    eye2 = jnp.eye(2, dtype=m_matrices.dtype)
-    m2 = jnp.einsum("ab,qij->qaibj", eye2, m_matrices).reshape(nq, 128, 128)
 
     pad = (-u) % tile_u
     x = jnp.pad(coeffs.astype(jnp.float32), ((0, pad), (0, 0)))
@@ -78,10 +80,10 @@ def fused_idct(
         in_specs=[
             pl.BlockSpec((tile_u, 64), lambda i: (i, 0)),
             pl.BlockSpec((tile_u, 1), lambda i: (i, 0)),
-            pl.BlockSpec((nq, 128, 128), lambda i: (0, 0, 0)),
+            pl.BlockSpec((nq, 64, 64), lambda i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_u, 64), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], 64), jnp.float32),
         interpret=interpret,
-    )(x, rows, m2)
+    )(x, rows, m_matrices.astype(jnp.float32))
     return out[:u]

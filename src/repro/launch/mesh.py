@@ -13,24 +13,31 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """Mesh over the visible devices with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which
+    ``with_sharding_constraint`` (dist/sharding.py) and the decoder's
+    GSPMD lane sharding do not accept; every mesh of this repo is built
+    here so the axis type is decided in one place.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (tests, CPU runs, PP variants)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n: Optional[int] = None, model: int = 1):
     """Small mesh over the locally visible devices (tests / examples)."""
     n = n or len(jax.devices())
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # -- host-aware meshes (multi-host launch, repro.launch.multihost) ----------
@@ -49,7 +56,7 @@ def make_local_data_mesh():
 
 def make_global_data_mesh():
     """1-D "data" mesh over every device of every process."""
-    return jax.make_mesh((jax.device_count(),), ("data",))
+    return make_mesh((jax.device_count(),), ("data",))
 
 
 def make_hosts_mesh():

@@ -92,13 +92,11 @@ def process_info() -> DistContext:
     """The ambient context: what jax already knows about the cluster.
 
     Safe to call whether or not :func:`init_distributed` ran — a plain
-    single-process jax reports (0, 1).
+    single-process jax reports (0, 1). A backend that fails to initialise
+    raises here instead of passing for a single process.
     """
     import jax
-    try:
-        pid, n = jax.process_index(), jax.process_count()
-    except Exception:  # pragma: no cover - backend not initializable
-        pid, n = 0, 1
+    pid, n = jax.process_index(), jax.process_count()
     return DistContext(process_id=int(pid), num_processes=int(n),
                        coordinator=_env_first("REPRO_COORDINATOR",
                                               "JAX_COORDINATOR_ADDRESS"),

@@ -17,7 +17,9 @@ def main():
     from ..configs import ARCH_IDS, get_smoke_config
     from ..models.model import init_caches, init_params
     from ..serve.step import make_decode_step, make_prefill_step
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3-8b")
     ap.add_argument("--batch", type=int, default=4)

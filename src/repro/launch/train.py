@@ -60,7 +60,9 @@ def main():
         save_checkpoint
     from ..train.optimizer import AdamWConfig, init_opt_state
     from ..train.step import make_train_step
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3-8b")
     ap.add_argument("--preset", choices=["smoke", "100m"], default="smoke")

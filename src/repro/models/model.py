@@ -334,7 +334,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
             nh = di // s.head_dim
             return SSMCache(
                 jnp.zeros((batch, s.d_conv - 1, di + 2 * s.d_state),
-                          jnp.bfloat16),
+                          jnp.dtype(cfg.dtype)),
                 jnp.zeros((batch, nh, s.d_state, s.head_dim), jnp.float32),
             )
         return None

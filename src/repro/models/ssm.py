@@ -134,10 +134,16 @@ def ssd_forward(
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + params[f"{name}.dt_bias"].astype(jnp.float32))
 
-    y, h_final = _ssd_chunked(xh, dt, a, bmat, cmat, s_cfg.chunk)
-    y = y + xh * params[f"{name}.d_skip"].astype(y.dtype)[None, None, :, None]
+    # the scan runs in f32 and rounds once, as ssd_decode_step does: a
+    # bf16 chunked scan drifts from the f32 recurrence the decode step
+    # continues from
+    f32 = jnp.float32
+    y, h_final = _ssd_chunked(xh.astype(f32), dt, a, bmat.astype(f32),
+                              cmat.astype(f32), s_cfg.chunk)
+    y = y + xh.astype(f32) * params[f"{name}.d_skip"].astype(f32)[
+        None, None, :, None]
     y = y.reshape(bsz, s, di)
-    y = rmsnorm(y * jax.nn.silu(z), params[f"{name}.out_norm"])
+    y = rmsnorm(y.astype(x.dtype) * jax.nn.silu(z), params[f"{name}.out_norm"])
     out = jnp.einsum("bse,ed->bsd", y.astype(x.dtype), params[f"{name}.w_out"])
 
     new_cache = None

@@ -555,7 +555,7 @@ def test_untainted_gather_not_flagged():
 def test_f64_scan_detects():
     import jax
     from repro.analysis import jaxpr_check as J
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         j64 = jax.make_jaxpr(lambda x: x * 2.0)(np.float64(1.5))
     assert J.scan_f64(j64)
     j32 = jax.make_jaxpr(lambda x: x * 2.0)(np.float32(1.5))

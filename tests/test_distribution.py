@@ -296,12 +296,8 @@ class TestMultiDevice:
             import dataclasses
             from functools import partial
             from jax.sharding import PartitionSpec as P
-            try:
-                from jax import shard_map          # jax >= 0.5
-                sm_kw = {"check_vma": False}
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
-                sm_kw = {"check_rep": False}
+            from jax import shard_map
+            sm_kw = {"check_vma": False}
             from repro.configs import get_smoke_config
             from repro.models.model import init_params, _embed_inputs, \
                 _run_stack, _logits

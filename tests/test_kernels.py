@@ -285,6 +285,15 @@ class TestBackendKnob:
         monkeypatch.delenv(KB.INTERPRET_ENV)
         import jax
         assert KB.default_interpret(None) is (jax.default_backend() == "cpu")
+        # on an accelerator the env var may not force the interpreter
+        monkeypatch.setattr(KB.jax, "default_backend", lambda: "tpu")
+        assert KB.default_interpret(None) is False
+        monkeypatch.setenv(KB.INTERPRET_ENV, "0")
+        assert KB.default_interpret(None) is False
+        monkeypatch.setenv(KB.INTERPRET_ENV, "1")
+        with pytest.raises(ValueError, match="interpreter on 'tpu'"):
+            KB.default_interpret(None)
+        assert KB.default_interpret(True) is True
 
 
 class TestColorKernel:
