@@ -61,7 +61,7 @@ import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.extend.core
+from jax.extend.core import ClosedJaxpr, Jaxpr, Var
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
@@ -98,18 +98,16 @@ def _src(eqn) -> str:
 # Jaxpr walking utilities
 # ---------------------------------------------------------------------------
 
+def _open(j):
+    """The Jaxpr of a ClosedJaxpr; anything else is returned as it is."""
+    return j.jaxpr if isinstance(j, ClosedJaxpr) else j
+
+
 def _subjaxprs(params):
     for v in params.values():
-        if isinstance(v, jax.extend.core.ClosedJaxpr):
-            yield v.jaxpr
-        elif isinstance(v, jax.extend.core.Jaxpr):
-            yield v
-        elif isinstance(v, (tuple, list)):
-            for x in v:
-                if isinstance(x, jax.extend.core.ClosedJaxpr):
-                    yield x.jaxpr
-                elif isinstance(x, jax.extend.core.Jaxpr):
-                    yield x
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(x, (ClosedJaxpr, Jaxpr)):
+                yield _open(x)
 
 
 def iter_eqns(jaxpr):
@@ -120,7 +118,7 @@ def iter_eqns(jaxpr):
 
 
 def _is_var(x) -> bool:
-    return isinstance(x, jax.extend.core.Var)
+    return isinstance(x, Var)
 
 
 _CALL_PRIMS = ("jit", "closed_call", "core_call", "custom_jvp_call",
@@ -174,7 +172,7 @@ def _as_where_select(eqn):
             break
     if body is None:
         return None
-    bj = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
+    bj = _open(body)
     if len(bj.outvars) != 1 or bj.constvars \
             or len(bj.invars) != len(eqn.invars):
         return None
@@ -256,8 +254,7 @@ class DefMap:
                         body = eqn.params[key]
                         break
                 if body is not None:
-                    bj = body.jaxpr if isinstance(
-                        body, jax.extend.core.ClosedJaxpr) else body
+                    bj = _open(body)
                     for bi, ai in zip(bj.invars, eqn.invars):
                         self.alias[bi] = ai
                     for ov, bv in zip(eqn.outvars, bj.outvars):
@@ -269,8 +266,7 @@ class DefMap:
                 # xs are not) — alias them so ref identity survives into
                 # the loop body
                 body = eqn.params["jaxpr"]
-                bj = body.jaxpr if isinstance(
-                    body, jax.extend.core.ClosedJaxpr) else body
+                bj = _open(body)
                 nc = eqn.params["num_consts"]
                 for bi, ai in zip(bj.invars[:nc], eqn.invars[:nc]):
                     self.alias[bi] = ai
@@ -536,7 +532,7 @@ class KernelInterp:
             for ov in eqn.outvars:
                 self.bind(ov, AV(None))
             return
-        bj = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
+        bj = _open(body)
         ins = []
         for a in eqn.invars:
             ri = self._ref_of(a)
@@ -941,7 +937,7 @@ class KernelInterp:
         nc, ncar = p["num_consts"], p["num_carry"]
         length = p["length"]
         body = p["jaxpr"]
-        bj = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
+        bj = _open(body)
 
         const_ins = []
         for a in eqn.invars[:nc]:
@@ -1042,7 +1038,7 @@ def find_pallas_calls(closed_jaxpr) -> List:
 def _index_map_ranges(bm, grid) -> List[IntRange]:
     """Interval-evaluate one BlockSpec index_map jaxpr over the grid."""
     imj = bm.index_map_jaxpr
-    jx = imj.jaxpr if isinstance(imj, jax.extend.core.ClosedJaxpr) else imj
+    jx = _open(imj)
     dm = DefMap().build(jx)
     interp = KernelInterp("index_map", {}, [], dm)
     in_avs = [AV(IntRange(0, max(0, g - 1))) for g in grid]
@@ -1087,7 +1083,7 @@ def check_kernel_bounds(pc_eqn, cell: str, contract, params: Dict[str, int]):
     (violations, interp) — the interp is reused by the scatter prover."""
     gm = pc_eqn.params["grid_mapping"]
     kj = pc_eqn.params["jaxpr"]
-    bj = kj.jaxpr if isinstance(kj, jax.extend.core.ClosedJaxpr) else kj
+    bj = _open(kj)
     dm = DefMap().build(bj)
     operand_contracts = list(contract.operands) if contract else []
     interp = KernelInterp(cell, params, operand_contracts, dm)
@@ -1240,7 +1236,7 @@ def self_increment_matches(dm: DefMap, bj, n_atom, run_atom) -> bool:
         if eqn.primitive.name != "scan":
             continue
         body = eqn.params["jaxpr"]
-        sub = body.jaxpr if isinstance(body, jax.extend.core.ClosedJaxpr) else body
+        sub = _open(body)
         n_root = dm.root(n_atom)
         for ov in sub.outvars:
             sel = dm.rootdef(ov)
