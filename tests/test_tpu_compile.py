@@ -2,7 +2,7 @@
 
 Nothing runs: the TPU compiler that ships with JAX compiles for a
 ``v5e:2x2`` topology that is described, not attached, and refuses what
-the chip's compiler would refuse (on-chip-measurement guide, §2). The
+the chip's compiler would refuse. The
 topology is made in a module-scoped fixture, never at import, so every
 pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library. The persistent compilation cache
