@@ -42,8 +42,10 @@ performs zero retraces at steady state (see docs/SERVING.md).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import itertools
 import threading
 import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -78,6 +80,55 @@ Array = jnp.ndarray
 _LANE_KEYS = ("chunk_start", "chunk_limit", "chunk_seg", "chunk_seq",
               "chunk_first", "chunk_seq_first", "chunk_prev", "chunk_next",
               "lane_perm", "chunk_order")
+
+
+# Spans and phases, on the profiler's clock. Host spans are
+# ``jax.profiler.TraceAnnotation``s named ``repro.*`` that carry the
+# batch's process-wide id (``batch=``) and its counters; they nest on the
+# calling thread. The entropy program's two device phases are named scopes,
+# kept in its compiled HLO's ``op_name`` metadata (:func:`hlo_phases`).
+# With the profiler off a span costs about a microsecond on the host.
+SYNC_PHASE = "repro.entropy.sync"
+WRITE_PHASE = "repro.entropy.write"
+_BATCH_IDS = itertools.count()
+
+
+def _span(name: str, batch: int, **counters):
+    return jax.profiler.TraceAnnotation(name, batch=batch, **counters)
+
+
+def hlo_phases(hlo_text: str) -> Dict[str, str]:
+    """``{entry-level instruction name: phase}`` of a compiled entropy
+    program's HLO text, for the instructions whose ``op_name`` lies under
+    :data:`SYNC_PHASE` or :data:`WRITE_PHASE`. Names are unique within a
+    module and an entry-level instruction's device time covers everything
+    nested in it, so summing the mapped instructions counts no time twice."""
+    entry = hlo_text[hlo_text.index("\nENTRY ") + 1:].splitlines()[1:]
+    out = {}
+    for line in itertools.takewhile(lambda x: x != "}", entry):
+        name = line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
+        scopes = line.partition('op_name="')[2].partition('"')[0].split("/")
+        for phase in (SYNC_PHASE, WRITE_PHASE):
+            if phase in scopes:
+                out[name.lstrip("%")] = phase
+    return out
+
+
+def _abstract(tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, weak_type=getattr(a, "weak_type", False)),
+        tree)
+
+
+def _trace_context(trace_token):
+    """Re-enter the (mesh, rules) context a trace token snapshots."""
+    stack = contextlib.ExitStack()
+    if trace_token is not None:
+        mesh, rules = trace_token
+        stack.enter_context(mesh)
+        stack.enter_context(S.logical_rules(dict(rules)))
+    return stack
 
 
 def _shard_lanes(dev: Dict[str, Array]) -> Dict[str, Array]:
@@ -200,6 +251,10 @@ class DecodeProgram:
         default_factory=threading.Lock, repr=False, compare=False)
     traced_keys: set = dataclasses.field(
         default_factory=set, repr=False, compare=False)
+    # abstract (words, dev, trace_token) of the first coeffs call, from
+    # which device_phases() lowers the program again
+    coeffs_args: Optional[tuple] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     @property
     def compiles(self) -> int:
@@ -219,8 +274,23 @@ class DecodeProgram:
         """``coeffs_fn`` with the first call per trace_token serialized
         (the operand shapes are fixed by the PlanShape, so the token is
         the only varying component of the jit key)."""
+        if self.coeffs_args is None:
+            self.coeffs_args = (_abstract(words), _abstract(dev), trace_token)
         return self._call_once_locked(("coeffs", trace_token),
                                       self.coeffs_fn, words, dev, trace_token)
+
+    def device_phases(self) -> Dict[str, str]:
+        """:func:`hlo_phases` of the compiled entropy program, lowered again
+        with the abstract arguments and in the (mesh, rules) context of its
+        first call, so from jit's cached trace (and a compile-cache hit
+        where the cache holds the program); ``{}`` before the first call.
+        For reading a profile after the fact, never for the hot path."""
+        if self.coeffs_args is None:
+            return {}
+        words, dev, token = self.coeffs_args
+        with _trace_context(token):
+            compiled = self.coeffs_fn.lower(words, dev, token).compile()
+        return hlo_phases(compiled.as_text())
 
     def call_pixels(self, pixdev, pix_layout, coeffs, trace_token):
         return self._call_once_locked(("pixels", trace_token),
@@ -376,83 +446,87 @@ def _build_program(shape: PlanShape, sync: str, backend: str,
             decode_exits = D.make_decode_exits(
                 s_max=sh.s_max, min_code_bits=sh.min_code_bits,
             )
-        # loop bounds are *capacities*: inert padding lanes decode nothing
-        # and are stable from round zero, so convergence is driven by the
-        # real lanes exactly as in the exact-fit program
-        if sync == "specmap":
-            from .bitstream import MAX_UPM
-            # specmap's round counter starts at max_upm (the hypothesis
-            # decodes count as rounds), so the verify budget must add it on
-            # top of the worst-case truth-propagation chain — n_chunks + 2
-            # alone starved verification by max_upm rounds and could return
-            # an unconverged (wrong) parse on long single-segment batches
-            res = specmap_sync(
-                dev, s_max=sh.s_max, min_code_bits=sh.min_code_bits,
-                max_upm=MAX_UPM, max_verify=sh.n_chunks + MAX_UPM + 2,
-                decode_exits=decode_exits, permuted=permuted,
-            )
-        elif sync == "jacobi":
-            res = jacobi_sync(
-                dev, s_max=sh.s_max, min_code_bits=sh.min_code_bits,
-                max_rounds=sh.n_chunks + 2, decode_exits=decode_exits,
-                permuted=permuted,
-            )
-        elif sync == "faithful":
-            res = faithful_sync(
-                dev, s_max=sh.s_max, min_code_bits=sh.min_code_bits,
-                seq_chunks=sh.seq_chunks, max_outer=sh.n_sequences + 2,
-                decode_exits=decode_exits, permuted=permuted,
-            )
-        else:  # sequential: one chunk per segment -> cold start is exact
-            exits = decode_exits(dev, DecodeState.cold(dev["chunk_start"]))
-            res = SyncResult(exits, jnp.asarray(1), jnp.asarray(True))
+        # the schedule, with jacobi's initial speculative pass (round 1)
+        with jax.named_scope(SYNC_PHASE):
+            # loop bounds are *capacities*: inert padding lanes decode nothing
+            # and are stable from round zero, so convergence is driven by the
+            # real lanes exactly as in the exact-fit program
+            if sync == "specmap":
+                from .bitstream import MAX_UPM
+                # specmap's round counter starts at max_upm (the hypothesis
+                # decodes count as rounds), so the verify budget must add it
+                # on top of the worst-case truth-propagation chain —
+                # n_chunks + 2 alone starved verification by max_upm rounds
+                # and could return an unconverged (wrong) parse on long
+                # single-segment batches
+                res = specmap_sync(
+                    dev, s_max=sh.s_max, min_code_bits=sh.min_code_bits,
+                    max_upm=MAX_UPM, max_verify=sh.n_chunks + MAX_UPM + 2,
+                    decode_exits=decode_exits, permuted=permuted,
+                )
+            elif sync == "jacobi":
+                res = jacobi_sync(
+                    dev, s_max=sh.s_max, min_code_bits=sh.min_code_bits,
+                    max_rounds=sh.n_chunks + 2, decode_exits=decode_exits,
+                    permuted=permuted,
+                )
+            elif sync == "faithful":
+                res = faithful_sync(
+                    dev, s_max=sh.s_max, min_code_bits=sh.min_code_bits,
+                    seq_chunks=sh.seq_chunks, max_outer=sh.n_sequences + 2,
+                    decode_exits=decode_exits, permuted=permuted,
+                )
+            else:  # sequential: one chunk per segment -> cold start is exact
+                exits = decode_exits(dev, DecodeState.cold(dev["chunk_start"]))
+                res = SyncResult(exits, jnp.asarray(1), jnp.asarray(True))
 
         # Output placement (Alg. 1 lines 7-8) + write pass (lines 9-15).
         # The final segment's write clamp comes from the *traced* scalar
         # units_end (the real batch's coefficient count) — pad segments
         # carry the same value in seg_coeff_base, so real lanes see
         # identical clamps whether or not the segment axis is padded.
-        bases = D.chunk_write_bases(dev, res.exits.n, permuted=permuted)
-        seg_end = jnp.concatenate([
-            dev["seg_coeff_base"][1:],
-            dev["units_end"][None],
-        ])
-        write_max = seg_end[dev["chunk_seg"]] - 1
-        entries = _entries_from(dev, res.exits, permuted)
-        out = jnp.zeros((sh.n_units * 64,), jnp.int32)
-        if backend == "pallas":
-            from ..kernels.fused import ops as FK
-            if fuse == "full" and FK.store_fusible(sh.n_units, mesh):
-                # fuse="full": the stream+scatter collapses into the
-                # in-kernel store; the gate re-evaluates per trace
-                # context (the mesh is part of the jit key), so sharded
-                # traces of the same program fall back to the stream form
-                prog.store_fused = True
-                _, out = FK.decode_coeffs_full(
-                    dev, entries, out=out, write_base=bases,
-                    write_max=write_max, s_max=sh.s_max,
-                    min_code_bits=sh.min_code_bits,
-                    chunk_bits=sh.chunk_bits, tile=write_tile,
-                    interpret=interpret,
-                )
+        with jax.named_scope(WRITE_PHASE):
+            bases = D.chunk_write_bases(dev, res.exits.n, permuted=permuted)
+            seg_end = jnp.concatenate([
+                dev["seg_coeff_base"][1:],
+                dev["units_end"][None],
+            ])
+            write_max = seg_end[dev["chunk_seg"]] - 1
+            entries = _entries_from(dev, res.exits, permuted)
+            out = jnp.zeros((sh.n_units * 64,), jnp.int32)
+            if backend == "pallas":
+                from ..kernels.fused import ops as FK
+                if fuse == "full" and FK.store_fusible(sh.n_units, mesh):
+                    # fuse="full": the stream+scatter collapses into the
+                    # in-kernel store; the gate re-evaluates per trace
+                    # context (the mesh is part of the jit key), so sharded
+                    # traces of the same program fall back to the stream form
+                    prog.store_fused = True
+                    _, out = FK.decode_coeffs_full(
+                        dev, entries, out=out, write_base=bases,
+                        write_max=write_max, s_max=sh.s_max,
+                        min_code_bits=sh.min_code_bits,
+                        chunk_bits=sh.chunk_bits, tile=write_tile,
+                        interpret=interpret,
+                    )
+                else:
+                    _, out = HK.decode_coeffs(
+                        dev, entries, out=out, write_base=bases,
+                        write_max=write_max, s_max=sh.s_max,
+                        min_code_bits=sh.min_code_bits,
+                        chunk_bits=sh.chunk_bits, tile=write_tile,
+                        interpret=interpret, mesh=mesh, lane_axis=lane_axis,
+                    )
             else:
-                _, out = HK.decode_coeffs(
-                    dev, entries, out=out, write_base=bases,
-                    write_max=write_max, s_max=sh.s_max,
-                    min_code_bits=sh.min_code_bits,
-                    chunk_bits=sh.chunk_bits, tile=write_tile,
-                    interpret=interpret, mesh=mesh, lane_axis=lane_axis,
+                meta = D.chunk_meta(dev)
+                _, out = D.decode_span(
+                    dev, entries, meta["word_base"], meta["limit"],
+                    meta["ts"], meta["upm"], s_max=sh.s_max,
+                    min_code_bits=sh.min_code_bits, write=True, out=out,
+                    write_base=bases, write_max=write_max,
                 )
-        else:
-            meta = D.chunk_meta(dev)
-            _, out = D.decode_span(
-                dev, entries, meta["word_base"], meta["limit"],
-                meta["ts"], meta["upm"], s_max=sh.s_max,
-                min_code_bits=sh.min_code_bits, write=True, out=out,
-                write_base=bases, write_max=write_max,
-            )
-        coeffs = out.reshape(sh.n_units, 64)
-        coeffs = S.shard(D.undiff_dc(dev, coeffs), "units", None)
+            coeffs = out.reshape(sh.n_units, 64)
+            coeffs = S.shard(D.undiff_dc(dev, coeffs), "units", None)
         return coeffs, res.rounds, res.converged
 
     prog.coeffs_fn = _coeffs
@@ -593,11 +667,14 @@ class ParallelDecoder:
                  shape: Optional[PlanShape] = None,
                  validation: Optional[BatchValidation] = None,
                  fuse: Optional[str] = None,
-                 tiles: Optional[TileConfig] = None):
+                 tiles: Optional[TileConfig] = None,
+                 batch_id: Optional[int] = None):
         assert sync in ("jacobi", "faithful", "sequential", "specmap")
         check_backend(backend)
         if backend == "pallas" and jax.default_backend() == "tpu":
             check_pallas_compiles(jax.devices()[0])
+        # the batch=... every host span of this batch carries
+        self.batch_id = next(_BATCH_IDS) if batch_id is None else batch_id
         self.sync = sync
         self.backend = backend
         self.interpret = interpret
@@ -607,26 +684,29 @@ class ParallelDecoder:
         # multi-host consensus path (repro.launch.multihost) hands every
         # process the merged shape so all hosts trace the same program;
         # build_plan_data validates the plan actually fits it
-        if shape is None:
-            shape = plan_shape(plan, bucket=bucket, step=ladder_step)
-            if (bucket and plan.image_status is not None
-                    and (plan.image_status != STATUS_OK).any()):
-                # quarantined batches borrow an existing compiled bucket
-                # that covers them, so quarantine never mints compile keys
-                shape = _quarantine_shape(plan, shape, sync, backend,
-                                          interpret, self.fuse)
-        # tile selection is per compile bucket; an explicit `tiles` pins it.
-        # autotune_tiles is memoized per bucket, so a quarantine-borrowed
-        # shape resolves to the same tiles its clean siblings compiled with
-        self.tiles = tiles if tiles is not None else (
-            autotune_tiles(shape, backend, self.fuse)
-            if backend == "pallas" else None)
-        if (shape.s_max, shape.min_code_bits, shape.n_images) != \
-                (plan.s_max, plan.min_code_bits, plan.n_images):
-            plan = consensus_plan(plan, shape)
-        self.plan = plan
-        self.shape = shape
-        self.data = build_plan_data(plan, self.shape)
+        with _span("repro.pad", self.batch_id):
+            if shape is None:
+                shape = plan_shape(plan, bucket=bucket, step=ladder_step)
+                if (bucket and plan.image_status is not None
+                        and (plan.image_status != STATUS_OK).any()):
+                    # quarantined batches borrow an existing compiled
+                    # bucket that covers them, so quarantine never mints
+                    # compile keys
+                    shape = _quarantine_shape(plan, shape, sync, backend,
+                                              interpret, self.fuse)
+            # tile selection is per compile bucket; an explicit `tiles`
+            # pins it. autotune_tiles is memoized per bucket, so a
+            # quarantine-borrowed shape resolves to the same tiles its
+            # clean siblings compiled with
+            self.tiles = tiles if tiles is not None else (
+                autotune_tiles(shape, backend, self.fuse)
+                if backend == "pallas" else None)
+            if (shape.s_max, shape.min_code_bits, shape.n_images) != \
+                    (plan.s_max, plan.min_code_bits, plan.n_images):
+                plan = consensus_plan(plan, shape)
+            self.plan = plan
+            self.shape = shape
+            self.data = build_plan_data(plan, self.shape)
         self.program = decode_program(self.shape, sync=sync, backend=backend,
                                       interpret=interpret,
                                       idct_impl=idct_impl,
@@ -634,16 +714,18 @@ class ParallelDecoder:
         # metadata operands live on device for the handle's lifetime; the
         # words buffer intentionally does NOT (each decode call uploads a
         # fresh copy and donates it to the compiled program)
-        self._dev_rest = {k: jnp.asarray(v)
-                          for k, v in self.data.arrays.items()}
-        if plan.uniform:
-            self._pixdev = {"m_matrices": self._dev_rest["m_matrices"],
-                            "unit_mrow": self._dev_rest["unit_mrow"]}
-            self._pix_layout = {
-                "comp_unit_idx": [jnp.asarray(a) for a in plan.comp_unit_idx],
-                "comp_block_idx": [jnp.asarray(a)
-                                   for a in plan.comp_block_idx],
-            }
+        with _span("repro.upload", self.batch_id):
+            self._dev_rest = {k: jnp.asarray(v)
+                              for k, v in self.data.arrays.items()}
+            if plan.uniform:
+                self._pixdev = {"m_matrices": self._dev_rest["m_matrices"],
+                                "unit_mrow": self._dev_rest["unit_mrow"]}
+                self._pix_layout = {
+                    "comp_unit_idx": [jnp.asarray(a)
+                                      for a in plan.comp_unit_idx],
+                    "comp_block_idx": [jnp.asarray(a)
+                                       for a in plan.comp_block_idx],
+                }
 
     @property
     def dev(self) -> Dict[str, Array]:
@@ -764,64 +846,89 @@ class ParallelDecoder:
         from ..dist import plan as DP
         DP.check_balance(balance)
         backend = resolve_backend(backend, use_kernels)
-        validation = None
-        if validate:
-            validation = validate_batch(blobs)
-            if sync == "sequential":
-                live = [(r.clean, r.rst_bits) for r in validation.reports
-                        if r.clean is not None]
-                if live:
-                    chunk_bits = _sequential_chunk_bits(live, bucket=bucket)
-            plan = build_batch_plan(blobs, chunk_bits=chunk_bits,
-                                    seq_chunks=seq_chunks,
-                                    validation=validation)
-        else:
-            images = [parse_jpeg(b) for b in blobs]
-            unstuffed = None
-            if sync == "sequential":
-                unstuffed = [unstuff_scan(img.scan_data) for img in images]
-                chunk_bits = _sequential_chunk_bits(unstuffed, bucket=bucket)
-            plan = build_batch_plan(blobs, chunk_bits=chunk_bits,
-                                    seq_chunks=seq_chunks, parsed=images,
-                                    unstuffed=unstuffed)
-        if balance != "none":
-            n_lanes = int(lanes) if lanes is not None else jax.device_count()
-            plan = DP.balance_lanes(plan, n_lanes, balance)
-        return cls(plan, sync=sync, idct_impl=idct_impl, backend=backend,
-                   interpret=interpret, bucket=bucket, validation=validation,
-                   fuse=fuse, tiles=tiles)
+        batch = next(_BATCH_IDS)
+        with _span("repro.from_bytes", batch):
+            validation = None
+            with _span("repro.parse", batch):
+                if validate:
+                    validation = validate_batch(blobs)
+                    unstuffed = [(r.clean, r.rst_bits)
+                                 for r in validation.reports
+                                 if r.clean is not None]
+                else:
+                    images = [parse_jpeg(b) for b in blobs]
+                    unstuffed = [unstuff_scan(img.scan_data)
+                                 for img in images]
+            with _span("repro.plan", batch):
+                if sync == "sequential" and unstuffed:
+                    chunk_bits = _sequential_chunk_bits(unstuffed,
+                                                        bucket=bucket)
+                if validate:
+                    plan = build_batch_plan(blobs, chunk_bits=chunk_bits,
+                                            seq_chunks=seq_chunks,
+                                            validation=validation)
+                else:
+                    plan = build_batch_plan(blobs, chunk_bits=chunk_bits,
+                                            seq_chunks=seq_chunks,
+                                            parsed=images,
+                                            unstuffed=unstuffed)
+                if balance != "none":
+                    n_lanes = (int(lanes) if lanes is not None
+                               else jax.device_count())
+                    plan = DP.balance_lanes(plan, n_lanes, balance)
+            return cls(plan, sync=sync, idct_impl=idct_impl,
+                       backend=backend, interpret=interpret, bucket=bucket,
+                       validation=validation, fuse=fuse, tiles=tiles,
+                       batch_id=batch)
 
     # -- execution ------------------------------------------------------------
     def coefficients(self) -> DecodeOutput:
+        with _span("repro.decode", self.batch_id):
+            return self._coefficients()
+
+    def _coefficients(self) -> DecodeOutput:
         # numpy in => jit transfers a fresh device buffer it may donate;
         # the capacity-sized output is sliced to the real unit count
         # host-side (a python int, so no retrace)
-        coeffs, rounds, conv = self.program.call_coeffs(
-            self.data.words, self._dev_rest, S.trace_token())
-        if coeffs.shape[0] != self.plan.total_units:
-            coeffs = _slice_units(coeffs, self.plan.total_units,
-                                  S.trace_token())
-        return DecodeOutput(coeffs, None, None, int(rounds), bool(conv),
-                            self.plan, status=self.plan.image_status,
+        batch, plan, shape = self.batch_id, self.plan, self.shape
+        with _span("repro.dispatch.entropy", batch, s_max=shape.s_max,
+                   lanes=shape.n_chunks, lanes_live=plan.n_chunks,
+                   units=plan.total_units, units_cap=shape.n_units):
+            coeffs, rounds, conv = self.program.call_coeffs(
+                self.data.words, self._dev_rest, S.trace_token())
+        if coeffs.shape[0] != plan.total_units:
+            with _span("repro.slice", batch):
+                coeffs = _slice_units(coeffs, plan.total_units,
+                                      S.trace_token())
+        # the host waits here for the entropy stage to finish
+        with _span("repro.rounds", batch) as span:
+            rounds, conv = int(rounds), bool(conv)
+            span.set_metadata(rounds=rounds)
+        return DecodeOutput(coeffs, None, None, rounds, conv, plan,
+                            status=plan.image_status,
                             validation=self.validation)
 
     def decode(self, emit: str = "rgb") -> DecodeOutput:
-        out = self.coefficients()
-        if emit == "coeffs":
-            return out
-        if not self.plan.uniform:
-            if self.plan.image_status is not None:
-                # validated decode: a batch can lose pixel-stage uniformity
-                # to quarantine (e.g. every image rejected) — degrade to
-                # coefficients instead of throwing, the status array tells
-                # the caller why
+        with _span("repro.decode", self.batch_id):
+            out = self._coefficients()
+            if emit == "coeffs":
                 return out
-            raise NotImplementedError(
-                "pixel stage requires a geometry-uniform batch; decode images "
-                "with mixed geometry via bucketing in repro.data.jpeg_pipeline"
-            )
-        planes, rgb = self.program.call_pixels(
-            self._pixdev, self._pix_layout, out.coeffs, S.trace_token())
+            if not self.plan.uniform:
+                if self.plan.image_status is not None:
+                    # validated decode: a batch can lose pixel-stage
+                    # uniformity to quarantine (e.g. every image rejected)
+                    # — degrade to coefficients instead of throwing, the
+                    # status array tells the caller why
+                    return out
+                raise NotImplementedError(
+                    "pixel stage requires a geometry-uniform batch; decode "
+                    "images with mixed geometry via bucketing in "
+                    "repro.data.jpeg_pipeline"
+                )
+            with _span("repro.dispatch.pixels", self.batch_id):
+                planes, rgb = self.program.call_pixels(
+                    self._pixdev, self._pix_layout, out.coeffs,
+                    S.trace_token())
         return dataclasses.replace(
             out, planes=planes, rgb=rgb if emit == "rgb" else None
         )

@@ -71,19 +71,31 @@ def compile_decode_programs(blobs, chunk_bits, sharding):
     return coeffs, pixels
 
 
-def test_jnp_decode_programs_compile_at_1080p(one_chip):
-    """One full-size ``newyork`` frame (1920x1080, q95, 1024-bit
-    subsequences): the main path's two programs compile for v5e and fit
-    its memory."""
+@pytest.fixture(scope="module")
+def programs_1080p(one_chip):
+    """The main path's two programs for one full-size ``newyork`` frame
+    (1920x1080, q95, 1024-bit subsequences), compiled for v5e."""
     from repro.jpeg.encoder import PAPER_DATASETS, build_dataset
     spec = dataclasses.replace(PAPER_DATASETS["newyork"], n_images=1)
     blobs = build_dataset(spec, seed=0).jpeg_bytes
-    for compiled in compile_decode_programs(blobs, spec.subsequence_bits,
-                                            one_chip):
+    return compile_decode_programs(blobs, spec.subsequence_bits, one_chip)
+
+
+def test_jnp_decode_programs_compile_at_1080p(programs_1080p):
+    """Both programs compile for v5e and fit its memory."""
+    for compiled in programs_1080p:
         mem = compiled.memory_analysis()
         need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes)
         assert 0 < need < HBM_BYTES
+
+
+def test_entropy_program_names_both_phases_on_v5e(programs_1080p):
+    """The named scopes of the entropy stage survive the TPU compiler: its
+    entry-level instructions map to the sync and the write phase."""
+    from repro.core import api
+    phases = api.hlo_phases(programs_1080p[0].as_text())
+    assert set(phases.values()) == {api.SYNC_PHASE, api.WRITE_PHASE}
 
 
 def _idct(one):
