@@ -238,6 +238,11 @@ class DecodeProgram:
     # the stream/unfused form, bit-identically
     store_fused: bool = False
     pixels_fused: bool = False
+    # the jnp symbol step's form, fixed by the shape when the program is
+    # built: "staged" reads each lane's words and LUT rows, staged once per
+    # batch, by a one-hot select (chunks of up to D.STAGE_MAX_WORDS words);
+    # "gather" gathers them every step (longer chunks, the Pallas backend)
+    step_staged: bool = False
 
     # First-call serialization (thread safety). jax.jit does not promise a
     # single trace under concurrent first calls from multiple threads, and
@@ -259,6 +264,10 @@ class DecodeProgram:
     @property
     def compiles(self) -> int:
         return self.coeffs_traces + self.pixels_traces
+
+    @property
+    def step(self) -> str:
+        return "staged" if self.step_staged else "gather"
 
     def _call_once_locked(self, key, fn, *args):
         if key in self.traced_keys:
@@ -359,7 +368,8 @@ def decode_program(shape: PlanShape, sync: str = "jacobi",
         return prog
     custom = DecodeProgram(shape=shape, sync=sync, backend=backend,
                            interpret=interpret, fuse=fuse, tiles=tiles,
-                           coeffs_fn=prog.coeffs_fn)
+                           coeffs_fn=prog.coeffs_fn,
+                           step_staged=prog.step_staged)
     if shape.uniform:
         custom.pixels_fn = _build_pixels_fn(shape, idct_impl, custom)
     return custom
@@ -387,7 +397,7 @@ def decode_program_stats() -> Dict:
         "pixels_compiles": sum(p.pixels_traces for p in progs),
         "buckets": [
             {"bucket": p.shape.label(), "sync": p.sync, "backend": p.backend,
-             "fuse": p.fuse, "compiles": p.compiles}
+             "fuse": p.fuse, "step": p.step, "compiles": p.compiles}
             for p in progs
         ],
     }
@@ -409,8 +419,10 @@ def _build_program(shape: PlanShape, sync: str, backend: str,
                    interpret: Optional[bool], idct_impl,
                    fuse: str = "none",
                    tiles: Optional[TileConfig] = None) -> DecodeProgram:
+    stage_w = D.stage_words(shape.chunk_bits) if backend == "jnp" else None
     prog = DecodeProgram(shape=shape, sync=sync, backend=backend,
-                         interpret=interpret, fuse=fuse, tiles=tiles)
+                         interpret=interpret, fuse=fuse, tiles=tiles,
+                         step_staged=stage_w is not None)
     exits_tile = tiles.exits_tile if tiles is not None else None
     write_tile = tiles.write_tile if tiles is not None else None
     if idct_impl is None and backend == "pallas":
@@ -448,6 +460,13 @@ def _build_program(shape: PlanShape, sync: str, backend: str,
             )
         # the schedule, with jacobi's initial speculative pass (round 1)
         with jax.named_scope(SYNC_PHASE):
+            if stage_w is not None:
+                # once per batch, for every symbol step of the sync
+                # schedule and of the write pass
+                lane_words, lane_rows = D.stage_lanes(dev, stage_w)
+                dev = dict(dev,
+                           lane_words=S.shard(lane_words, None, "chunks"),
+                           lane_rows=S.shard(lane_rows, None, "chunks"))
             # loop bounds are *capacities*: inert padding lanes decode nothing
             # and are stable from round zero, so convergence is driven by the
             # real lanes exactly as in the exact-fit program
@@ -524,6 +543,7 @@ def _build_program(shape: PlanShape, sync: str, backend: str,
                     meta["ts"], meta["upm"], s_max=sh.s_max,
                     min_code_bits=sh.min_code_bits, write=True, out=out,
                     write_base=bases, write_max=write_max,
+                    stage=meta["stage"],
                 )
             coeffs = out.reshape(sh.n_units, 64)
             coeffs = S.shard(D.undiff_dc(dev, coeffs), "units", None)
@@ -893,7 +913,8 @@ class ParallelDecoder:
         batch, plan, shape = self.batch_id, self.plan, self.shape
         with _span("repro.dispatch.entropy", batch, s_max=shape.s_max,
                    lanes=shape.n_chunks, lanes_live=plan.n_chunks,
-                   units=plan.total_units, units_cap=shape.n_units):
+                   units=plan.total_units, units_cap=shape.n_units,
+                   step=self.program.step):
             coeffs, rounds, conv = self.program.call_coeffs(
                 self.data.words, self._dev_rest, S.trace_token())
         if coeffs.shape[0] != plan.total_units:

@@ -4,7 +4,9 @@ The decode primitive is :func:`decode_span`: a bulk-synchronous, lane-
 vectorized version of the paper's ``decode_subsequence`` (Algorithm 2). One
 "lane" per chunk; each loop iteration decodes one Huffman symbol per lane via
 a 16-bit-lookahead LUT gather — the TPU-shaped equivalent of the CUDA
-per-thread bit loop (DESIGN.md §3).
+per-thread bit loop (DESIGN.md §3). Where chunks are short, each lane's
+words and LUT rows are staged once per batch (:func:`stage_lanes`) and the
+step reads them by a one-hot select, so the LUT lookup is its only gather.
 
 All functions take `dev`, the device pytree from BatchPlan.device_arrays().
 """
@@ -27,14 +29,93 @@ I32 = jnp.int32
 # Bit window fetch
 # ---------------------------------------------------------------------------
 
+def _window32(hi: jnp.ndarray, lo: jnp.ndarray, p: jnp.ndarray):
+    off = (p & 31).astype(U32)
+    lo_shift = jnp.where(off == 0, U32(0), lo >> ((U32(32) - off) & U32(31)))
+    return (hi << off) | lo_shift
+
+
 def fetch_window32(words: jnp.ndarray, word_base: jnp.ndarray, p: jnp.ndarray):
     """32-bit MSB-aligned window starting at bit `p` of each lane's segment."""
     w = word_base + (p >> 5)
-    off = (p & 31).astype(U32)
-    hi = words[w]
-    lo = words[w + 1]
-    lo_shift = jnp.where(off == 0, U32(0), lo >> ((U32(32) - off) & U32(31)))
-    return (hi << off) | lo_shift
+    return _window32(words[w], words[w + 1], p)
+
+
+# ---------------------------------------------------------------------------
+# Per-lane operands staged once per batch
+# ---------------------------------------------------------------------------
+#
+# A gather on the chip costs per index, whatever the table's size: on a
+# v5e about 6 ns a lane for a word and 10 ns for the LUT row, from a table
+# of 12 entries. A one-hot select over a lane's W staged words costs W
+# compare-selects per lane on the vector unit: at W = 34 and 11,547 lanes
+# the word select takes 0.66 us a step on a v5e and the row select 0.21,
+# where the three gathers took about 260 us. The select's cost grows with
+# W and the gathers' does not; up to STAGE_MAX_WORDS (chunks of up to
+# 4,096 bits) it reads at most 520 bytes a lane, under a nanosecond at HBM
+# speed. Longer chunks (the sequential schedule's one chunk per segment,
+# W in the thousands, every lane padded to the longest segment) keep the
+# gather form.
+STAGE_MAX_WORDS = 130
+
+
+class LaneStage(NamedTuple):
+    """A lane's operands for the gather-free step, lanes minor."""
+    words: jnp.ndarray   # (W, C) uint32: word k of the lane's chunk
+    rows: jnp.ndarray    # (2 * MAX_UPM, C) int32: LUT row at u * 2 + is_dc
+    word0: jnp.ndarray   # (C,) int32: chunk_start >> 5, the lane's word 0
+
+
+def stage_words(chunk_bits: int) -> Optional[int]:
+    """W, the words a lane of `chunk_bits` chunks reads (its chunk's, the
+    word a window straddles into, and one spare), or None where W is past
+    :data:`STAGE_MAX_WORDS` and the step keeps its gathers."""
+    w = chunk_bits // 32 + 2
+    return w if w <= STAGE_MAX_WORDS else None
+
+
+def stage_lanes(dev: Dict[str, jnp.ndarray], w: int):
+    """``(lane_words, lane_rows)`` of every chunk lane, lanes minor, for
+    windows of ``w`` words (:func:`stage_words`).
+
+    Word k of lane c is ``words[word_base[c] + (chunk_start[c] >> 5) + k]``,
+    clamped to the buffer as jnp's gather clamps, so a select of word
+    ``(p >> 5) - (chunk_start >> 5)`` equals the gather form bit for bit.
+    The rows are ``unit_lut_row[ts[c]]`` flattened over (u, is_dc).
+    """
+    seg = dev["chunk_seg"]
+    first = dev["seg_word_base"][seg] + (dev["chunk_start"] >> 5)
+    gidx = first[None, :] + jnp.arange(w, dtype=I32)[:, None]
+    words = dev["words"]
+    lane_words = words[jnp.minimum(gidx, words.shape[0] - 1)]
+    rows = dev["unit_lut_row"][dev["seg_tableset"][seg]]
+    return lane_words, rows.reshape(rows.shape[0], -1).T
+
+
+def _select_rows(table: jnp.ndarray, k: jnp.ndarray):
+    """``table[k[c], c]`` for every lane c by a one-hot compare-select over
+    the rows (0 where k[c] is out of range)."""
+    at = jnp.arange(table.shape[0], dtype=I32)[:, None] == k
+    return jnp.sum(jnp.where(at, table, 0), axis=0, dtype=table.dtype)
+
+
+def fetch_window32_staged(stage: LaneStage, p: jnp.ndarray):
+    """:func:`fetch_window32` from the staged words, with no gather.
+
+    The select relies on this invariant: for every *active* lane
+    (p < limit), 0 <= (p >> 5) - (chunk_start >> 5) <= W - 2. An entry is
+    never before its chunk's start (a cold entry is the start; a chained
+    one is the predecessor's exit, at or past the predecessor's limit,
+    which is this chunk's start), and p < limit <= chunk_start +
+    chunk_bits, so the window's two words lie inside the chunk's
+    chunk_bits // 32 + 1 words. An inactive lane may select nothing and
+    read 0: its state does not move and the write pass drops its
+    coefficient, both masked on ``active``.
+    """
+    k = (p >> 5) - stage.word0
+    hi = _select_rows(stage.words, k)
+    lo = _select_rows(stage.words, k + 1)
+    return _window32(hi, lo, p)
 
 
 # ---------------------------------------------------------------------------
@@ -57,14 +138,23 @@ def decode_symbol(
     ts: jnp.ndarray,
     upm: jnp.ndarray,
     min_code_bits: int,
+    stage: Optional[LaneStage] = None,
 ) -> StepOut:
-    """decode_next_symbol() from the paper, for all lanes at once."""
-    active = st.p < limit
-    win32 = fetch_window32(dev["words"], word_base, st.p)
-    win16 = (win32 >> U32(16)).astype(I32)
+    """decode_next_symbol() from the paper, for all lanes at once.
 
+    With a `stage` the words and the LUT row come from the staged operands
+    by a one-hot select (`word_base` and `ts` then go unread); without, by
+    gathers. The two give the same step bit for bit.
+    """
+    active = st.p < limit
     is_dc = (st.z == 0).astype(I32)
-    row = dev["unit_lut_row"][ts, st.u, is_dc]
+    if stage is None:
+        win32 = fetch_window32(dev["words"], word_base, st.p)
+        row = dev["unit_lut_row"][ts, st.u, is_dc]
+    else:
+        win32 = fetch_window32_staged(stage, st.p)
+        row = _select_rows(stage.rows, st.u * 2 + is_dc)
+    win16 = (win32 >> U32(16)).astype(I32)
     entry = dev["luts"][row, win16]
 
     clen = entry & 0x1F
@@ -119,12 +209,14 @@ def decode_span(
     out: Optional[jnp.ndarray] = None,
     write_base: Optional[jnp.ndarray] = None,
     write_max: Optional[jnp.ndarray] = None,
+    stage: Optional[LaneStage] = None,
 ) -> Tuple[DecodeState, Optional[jnp.ndarray]]:
     """Decode every lane from its entry state to the end of its bit range.
 
     Returns the exit states (with per-chunk n counts). When `write=True`,
     coefficients are scattered into `out` at write_base + local_n + run and
-    the updated buffer is returned.
+    the updated buffer is returned. `stage` (``chunk_meta(...)["stage"]``)
+    selects the gather-free step.
     """
     st0 = DecodeState(entry.p, entry.u, entry.z, jnp.zeros_like(entry.p))
 
@@ -133,7 +225,8 @@ def decode_span(
 
         def body(_, carry):
             st, buf = carry
-            o = decode_symbol(dev, st, word_base, limit, ts, upm, min_code_bits)
+            o = decode_symbol(dev, st, word_base, limit, ts, upm,
+                              min_code_bits, stage)
             idx = write_base + st.n + o.run
             ok = o.active & (~o.invalid) & (idx <= write_max)
             # NB: sentinel must be past-the-end, not -1 (negative indices wrap).
@@ -150,22 +243,36 @@ def decode_span(
         return st, out
 
     def body(_, st):
-        return decode_symbol(dev, st, word_base, limit, ts, upm, min_code_bits).state
+        return decode_symbol(dev, st, word_base, limit, ts, upm,
+                             min_code_bits, stage).state
 
     st = jax.lax.fori_loop(0, s_max, body, st0)
     return st, None
 
 
 def chunk_meta(dev: Dict[str, jnp.ndarray], idx: Optional[jnp.ndarray] = None):
-    """Gather per-chunk decode metadata (optionally at a chunk-index subset)."""
+    """Gather per-chunk decode metadata (optionally at a chunk-index subset).
+
+    ``stage`` is the lanes' :class:`LaneStage` where `dev` holds the staged
+    operands (``lane_words``, ``lane_rows``), else None; at a subset it is
+    gathered once here, outside the symbol loop.
+    """
     seg = dev["chunk_seg"] if idx is None else dev["chunk_seg"][idx]
     limit = dev["chunk_limit"] if idx is None else dev["chunk_limit"][idx]
     ts = dev["seg_tableset"][seg]
+    stage = None
+    if "lane_words" in dev:
+        stage = LaneStage(dev["lane_words"], dev["lane_rows"],
+                          dev["chunk_start"] >> 5)
+        if idx is not None:
+            stage = LaneStage(stage.words[:, idx], stage.rows[:, idx],
+                              stage.word0[idx])
     return dict(
         word_base=dev["seg_word_base"][seg],
         limit=limit,
         ts=ts,
         upm=dev["ts_upm"][ts],
+        stage=stage,
     )
 
 
@@ -182,7 +289,7 @@ def make_decode_exits(*, s_max: int, min_code_bits: int):
         m = chunk_meta(dev, idx)
         st, _ = decode_span(
             dev, entry, m["word_base"], m["limit"], m["ts"], m["upm"],
-            s_max=s_max, min_code_bits=min_code_bits,
+            s_max=s_max, min_code_bits=min_code_bits, stage=m["stage"],
         )
         return st
     return fn

@@ -20,9 +20,12 @@ from repro.core import (
     build_batch_plan,
 )
 from repro.core import decode as D
-from repro.core.sync import faithful_sync, jacobi_sync
+from repro.core.sync import (
+    chain_entries, faithful_sync, jacobi_sync, specmap_sync,
+)
 from repro.jpeg import codec_ref as cr
 
+import jax
 import jax.numpy as jnp
 
 from conftest import synth_image
@@ -196,6 +199,135 @@ class TestSyncSchedulesAgree:
         assert bool(ja.converged) and bool(fa.converged)
         for a, b in zip(ja.exits, fa.exits):
             assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def _stage(dev, chunk_bits):
+    lane_words, lane_rows = D.stage_lanes(dev, D.stage_words(chunk_bits))
+    return dict(dev, lane_words=lane_words, lane_rows=lane_rows)
+
+
+def _schedule(sync, dev, sh):
+    """The entropy program's call of each schedule, as core/api builds it."""
+    from repro.core.bitstream import MAX_UPM
+    kw = dict(s_max=sh.s_max, min_code_bits=sh.min_code_bits,
+              permuted=sh.permuted)
+    if sync == "jacobi":
+        return jacobi_sync(dev, max_rounds=sh.n_chunks + 2, **kw)
+    if sync == "faithful":
+        return faithful_sync(dev, seq_chunks=sh.seq_chunks,
+                             max_outer=sh.n_sequences + 2, **kw)
+    return specmap_sync(dev, max_upm=MAX_UPM,
+                        max_verify=sh.n_chunks + MAX_UPM + 2, **kw)
+
+
+class TestStagedStep:
+    """The symbol step that reads staged lane operands by a one-hot select
+    gives what the gather form gives, bit for bit, and the form follows the
+    chunk's width W alone."""
+
+    @staticmethod
+    def _decoder(chunk_bits, balance="none", sync="jacobi"):
+        imgs = [synth_image(40, 56, seed=10 + i, noise=18.0)
+                for i in range(3)]
+        blobs = [cr.encode_baseline(im, quality=q).jpeg_bytes
+                 for im, q in zip(imgs, (35, 70, 92))]
+        return ParallelDecoder.from_bytes(
+            blobs, chunk_bits=chunk_bits, seq_chunks=2, sync=sync,
+            balance=balance, lanes=2)
+
+    @pytest.mark.parametrize("chunk_bits", [128, 1024])
+    @pytest.mark.parametrize("balance", ["none", "lpt"])
+    @pytest.mark.parametrize("sync", ["jacobi", "faithful", "specmap"])
+    def test_staged_step_matches_gather_form(self, sync, balance, chunk_bits,
+                                             monkeypatch):
+        from repro.core import api
+        dec = self._decoder(chunk_bits, balance, sync)
+        sh = dec.shape
+        assert sh.n_units > dec.plan.total_units     # capacity-padded
+        assert sh.permuted == (balance != "none")
+        dev = dec.dev
+
+        run = jax.jit(lambda d: _schedule(sync, d, sh))
+        gather, staged = run(dev), run(_stage(dev, sh.chunk_bits))
+        assert bool(gather.converged) and bool(staged.converged)
+        assert int(gather.rounds) == int(staged.rounds)
+        for a, b in zip(gather.exits, staged.exits):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+        def coeffs(prog):
+            return prog.call_coeffs(dec.data.words, dec._dev_rest, None)
+
+        staged_prog = api._build_program(sh, sync, "jnp", None, None)
+        monkeypatch.setattr(D, "STAGE_MAX_WORDS", 0)
+        gather_prog = api._build_program(sh, sync, "jnp", None, None)
+        assert staged_prog.step_staged and not gather_prog.step_staged
+        (c_g, r_g, ok_g), (c_s, r_s, ok_s) = (coeffs(gather_prog),
+                                              coeffs(staged_prog))
+        assert bool(ok_g) and bool(ok_s)
+        assert int(r_g) == int(r_s) == int(staged.rounds)
+        assert np.array_equal(np.asarray(c_g), np.asarray(c_s))
+
+    @pytest.mark.parametrize("quality,step", [(90, "gather"), (35, "staged")])
+    def test_sequential_form_follows_its_segment_width(self, quality, step):
+        """Sequential chunks are whole segments: past STAGE_MAX_WORDS words
+        they keep the gather form; a batch of tiny segments stages."""
+        r = cr.encode_baseline(synth_image(48, 64, seed=0), quality=quality)
+        dec = ParallelDecoder.from_bytes([r.jpeg_bytes], sync="sequential")
+        assert dec.program.step == step
+        assert (D.stage_words(dec.shape.chunk_bits) is None) == (
+            step == "gather")
+        out = dec.coefficients()
+        assert np.array_equal(np.asarray(out.coeffs), oracle_coeffs([r]))
+
+    @pytest.mark.parametrize("chunk_bits", [128, 1024])
+    @pytest.mark.parametrize("entries", ["garbage", "converged"])
+    def test_active_lanes_select_inside_their_words(self, entries,
+                                                    chunk_bits):
+        """Every active lane's window lies at staged words k and k + 1 with
+        0 <= k <= W - 2, at every step, and both forms step alike: from the
+        chained exits of the cold pass (round 2's entries, whose
+        predecessors ran past their limits) and from converged exits."""
+        dec = self._decoder(chunk_bits)
+        sh, dev = dec.shape, dec.dev
+        w = D.stage_words(sh.chunk_bits)
+        sdev = _stage(dev, sh.chunk_bits)
+        m, ms = D.chunk_meta(dev), D.chunk_meta(sdev)
+        exits_fn = D.make_decode_exits(s_max=sh.s_max,
+                                       min_code_bits=sh.min_code_bits)
+        if entries == "garbage":
+            exits = exits_fn(dev, DecodeState.cold(dev["chunk_start"]))
+        else:
+            exits = _schedule("jacobi", dev, sh).exits
+        entry = chain_entries(dev, exits)
+        past = np.asarray(entry.p) > np.asarray(dev["chunk_start"])
+        assert past.any()
+
+        def body(carry, _):
+            sg, ss = carry
+            og = D.decode_symbol(dev, sg, m["word_base"], m["limit"], m["ts"],
+                                 m["upm"], sh.min_code_bits)
+            os_ = D.decode_symbol(sdev, ss, m["word_base"], m["limit"],
+                                  m["ts"], m["upm"], sh.min_code_bits,
+                                  ms["stage"])
+            k = (ss.p >> 5) - ms["stage"].word0
+            act = ss.p < m["limit"]
+            same = (jnp.all(og.active == os_.active)
+                    & jnp.all(og.state.puz_equal(os_.state))
+                    & jnp.all(og.state.n == os_.state.n)
+                    & jnp.all(jnp.where(og.active, og.coef == os_.coef, True)))
+            return (og.state, os_.state), (
+                jnp.min(jnp.where(act, k, w)), jnp.max(jnp.where(act, k, -1)),
+                jnp.sum(act), same)
+
+        st0 = DecodeState(entry.p, entry.u, entry.z, jnp.zeros_like(entry.p))
+        _, (k_lo, k_hi, n_act, same) = jax.jit(lambda s: jax.lax.scan(
+            body, (s, s), None, length=sh.s_max))(st0)
+        assert int(n_act[0]) > 0 and int(n_act[-1]) == 0
+        # the window's two words: the chunk's own and the one it straddles
+        # into, inside the W staged
+        assert int(k_lo.min()) >= 0
+        assert int(k_hi.max()) <= sh.chunk_bits // 32 - 1 <= w - 2
+        assert bool(np.all(same))
 
 
 class TestDecodeEdgePaths:

@@ -23,7 +23,7 @@ from conftest import synth_image
 FROM_BYTES = ["repro.parse", "repro.plan", "repro.pad", "repro.upload"]
 DECODE = ["repro.dispatch.entropy", "repro.slice", "repro.rounds",
           "repro.dispatch.pixels"]
-COUNTERS = {"s_max", "lanes", "lanes_live", "units", "units_cap"}
+COUNTERS = {"s_max", "lanes", "lanes_live", "units", "units_cap", "step"}
 
 
 def batch(seed):
@@ -103,7 +103,31 @@ def test_every_span_nests_under_its_batch(profiled):
         assert args["lanes_live"] == dec.plan.n_chunks
         assert args["units"] == dec.plan.total_units
         assert args["units_cap"] == dec.shape.n_units
+        assert args["step"] == dec.program.step
         assert mine["repro.rounds"][3]["rounds"] == out.sync_rounds
+
+
+def test_the_entropy_span_names_the_step_form(tmp_path):
+    """A 1,024-bit plan runs the staged step and a sequential plan of
+    segment-long chunks the gather form; the span and the program stats
+    say which ran."""
+    blobs = [cr.encode_baseline(synth_image(48, 64, seed=40),
+                                quality=90).jpeg_bytes]
+    decs = [ParallelDecoder.from_bytes(blobs, chunk_bits=1024),
+            ParallelDecoder.from_bytes(blobs, sync="sequential")]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for dec in decs:
+            dec.coefficients().coeffs.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    steps = {s[3]["batch"]: s[3]["step"] for s in program_spans(tmp_path)
+             if s[0] == "repro.dispatch.entropy"}
+    assert [steps[d.batch_id] for d in decs] == ["staged", "gather"]
+    buckets = {(b["bucket"], b["sync"]): b["step"]
+               for b in api.decode_program_stats()["buckets"]}
+    assert [buckets[(d.shape.label(), d.program.sync)] for d in decs] == [
+        "staged", "gather"]
 
 
 def test_a_decoder_built_from_a_plan_draws_its_own_batch_id():

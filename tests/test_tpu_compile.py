@@ -11,6 +11,8 @@ without a chip.
 """
 import dataclasses
 import os
+import re
+from typing import NamedTuple
 
 import pytest
 
@@ -49,10 +51,17 @@ def _sds(tree, sharding):
         tree)
 
 
+class Compiled(NamedTuple):
+    coeffs: object      # the compiled entropy stage
+    pixels: object      # the compiled pixel stage
+    program: object     # their DecodeProgram
+
+
 def compile_decode_programs(blobs, chunk_bits, sharding):
     """Lower and compile the jnp backend's two programs (entropy stage
     ``coeffs_fn`` and pixel stage ``pixels_fn``) for ``blobs``' bucket on
-    the device behind ``sharding``. Returns the two compiled programs."""
+    the device behind ``sharding``. Returns both compiled programs and the
+    :class:`DecodeProgram` they came from."""
     from repro.core import ParallelDecoder
     from repro.dist import sharding as S
 
@@ -68,7 +77,7 @@ def compile_decode_programs(blobs, chunk_bits, sharding):
     pixels = prog.pixels_fn.lower(
         _sds(dec._pixdev, sharding), _sds(dec._pix_layout, sharding), units,
         token).compile()
-    return coeffs, pixels
+    return Compiled(coeffs, pixels, prog)
 
 
 @pytest.fixture(scope="module")
@@ -83,7 +92,7 @@ def programs_1080p(one_chip):
 
 def test_jnp_decode_programs_compile_at_1080p(programs_1080p):
     """Both programs compile for v5e and fit its memory."""
-    for compiled in programs_1080p:
+    for compiled in (programs_1080p.coeffs, programs_1080p.pixels):
         mem = compiled.memory_analysis()
         need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
                 + mem.output_size_in_bytes)
@@ -94,8 +103,64 @@ def test_entropy_program_names_both_phases_on_v5e(programs_1080p):
     """The named scopes of the entropy stage survive the TPU compiler: its
     entry-level instructions map to the sync and the write phase."""
     from repro.core import api
-    phases = api.hlo_phases(programs_1080p[0].as_text())
+    phases = api.hlo_phases(programs_1080p.coeffs.as_text())
     assert set(phases.values()) == {api.SYNC_PHASE, api.WRITE_PHASE}
+
+
+def _computations(hlo_text):
+    """``{computation name: its instruction lines}`` of HLO text."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _reached(comps, root):
+    """The computations ``root`` runs, nested loops' bodies left out."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            if " while(" in line:
+                continue
+            todo += re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line)
+    return seen
+
+
+def test_symbol_step_loops_keep_only_the_lut_gather(programs_1080p):
+    """Each symbol-step loop of the 1080p entropy program (the innermost
+    loops: the initial pass, the rounds' step and the write pass) holds
+    one gather, and it reads the LUTs: the words and the LUT row are
+    staged and selected without one."""
+    assert programs_1080p.program.step_staged
+    luts = programs_1080p.program.shape.n_luts
+    comps = _computations(programs_1080p.coeffs.as_text())
+    bodies = [re.search(r"body=%([\w.\-]+)", line).group(1)
+              for lines in comps.values() for line in lines
+              if " while(" in line]
+    steps = [b for b in bodies
+             if not any(" while(" in line for c in _reached(comps, b)
+                        for line in comps[c])]
+    assert len(steps) == 3
+    for body in steps:
+        reached = _reached(comps, body)
+        gathers = [(c, line) for c in reached for line in comps[c]
+                   if re.search(r"[ )]gather\(", line.split(" = ", 1)[-1])]
+        assert len(gathers) == 1, gathers
+        comp, line = gathers[0]
+        table = re.search(r"gather\(%([\w.\-]+)", line).group(1)
+        (decl,) = [x for x in comps[comp] if x.startswith(f"%{table} = ")]
+        assert f"s32[{luts},65536]" in decl
 
 
 def _idct(one):
