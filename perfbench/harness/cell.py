@@ -87,6 +87,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, t_start: float
     def say(msg):
         print(msg, file=out, flush=True)
 
+    resize = check.answers_spec(cell.config)
     pending = corpus.start(cell.config, pool)
     devices = start_jax(cell.chips, require_tpu)
     kind = devices[0].device_kind
@@ -143,7 +144,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, t_start: float
     samples, attempted, failed = win.samples, win.attempted, win.failed
     del win
     t0 = time.perf_counter()
-    numbers = check.compare(samples, pool, control=control)
+    numbers = check.compare(samples, pool, control=control, resize=resize)
     correct, checks = check.verdict(numbers, cell.config["limits"])
     say(f"check: {numbers['images']} answers against the reference in "
         f"{time.perf_counter() - t0:.1f} s; largest RGB difference "

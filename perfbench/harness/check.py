@@ -19,6 +19,12 @@ move about one sample in seventy by 1 (the reference at that precision).
 ``control=<precision>`` puts the reference itself in the program's place,
 with its pixels computed at one of ``CONTROLS`` (``reference.pixels``), a
 step below what the configurations state: the comparison must refuse each.
+
+An answer with a ``crop`` (``window.Sample``) is compared with the
+reference's crop of its own RGB, resized by the method the configuration
+names under ``answers.resize`` (``reference.crop_resize``); a control's
+pixels go through the same crop. A one-component file's answer is its
+(H, W) plane.
 """
 from __future__ import annotations
 
@@ -31,13 +37,33 @@ NUMBERS = ("missing", "coeff_wrong", "rgb_off")
 CONTROLS = ("bfloat16", "float8_idct")
 
 
+ANSWERS_KEYS = {"resize"}
+RESIZE_METHODS = (reference.RESIZE,)
+
+
+def answers_spec(config: dict):
+    """The resize method the configuration states for crop answers, or
+    None; ValueError on a key or a method the comparison cannot honour."""
+    answers = config.get("answers", {})
+    if not isinstance(answers, dict) or set(answers) - ANSWERS_KEYS:
+        raise ValueError(f"answers: only {sorted(ANSWERS_KEYS)} can be stated")
+    resize = answers.get("resize")
+    if resize is not None and resize not in RESIZE_METHODS:
+        raise ValueError(f"answers.resize: {resize!r} is none of {RESIZE_METHODS}")
+    return resize
+
+
+def _answer(img, crop):
+    return img if crop is None else reference.crop_resize(img, crop)
+
+
 def judge(task) -> dict:
     """Pool task: compare one answer with the reference."""
-    blob, coeffs, rgb, control = task
+    blob, coeffs, rgb, crop, control = task
     fr, ref_c = reference.coefficients(blob)
-    ref_rgb = reference.pixels(fr, ref_c)
+    ref_rgb = _answer(reference.pixels(fr, ref_c), crop)
     if control:
-        coeffs, rgb = ref_c, reference.pixels(fr, ref_c, control)
+        coeffs, rgb = ref_c, _answer(reference.pixels(fr, ref_c, control), crop)
     out = {"coeff_wrong": 0, "rgb_off": 0, "rgb_ne": 0, "rgb_max": 0,
            "samples": int(ref_rgb.size)}
     if coeffs is not None:
@@ -56,11 +82,16 @@ def judge(task) -> dict:
     return out
 
 
-def compare(samples, pool, control=None) -> dict:
+def compare(samples, pool, control=None, resize=None) -> dict:
     """The compared numbers over ``samples`` (``window.Sample``), and what
-    stands behind them."""
+    stands behind them. ``resize``: the configuration's ``answers_spec``,
+    which crop answers need."""
+    if resize not in RESIZE_METHODS and any(s.crop is not None for s in samples):
+        raise ValueError("crop answers, and the configuration states no "
+                         "answers.resize")
     missing = sum(s.missing for s in samples)
-    tasks = [(s.blob, s.coeffs, s.rgb, control) for s in samples if not s.missing]
+    tasks = [(s.blob, s.coeffs, s.rgb, s.crop, control)
+             for s in samples if not s.missing]
     parts = pool.map(judge, tasks, chunksize=1) if tasks else []
     n = sum(p["samples"] for p in parts)
     return {

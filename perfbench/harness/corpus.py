@@ -9,8 +9,24 @@ its file states (``corpus.seed``), as the paper's corpora are fixed sets:
 a run's ``--seed`` orders and samples it and never changes the work. Frames
 are encoded in a process pool whose workers import numpy only, never JAX,
 and the corpus is cached under ``.bench_datasets/`` by (configuration,
-size, digest of this file and of the tables it uses), so that only the
-first run in a checkout encodes it.
+its kinds, size, digest of this file and of the tables it uses), so that
+only the first run in a checkout encodes it.
+
+The configuration's ``corpus`` states its frames in one of two forms:
+
+- one kind: ``width``, ``height``, ``quality``, ``subsampling``, and
+  optionally ``restart_interval`` (MCUs, 0 for none) and ``tables``;
+- ``kinds``: a list of kinds, each with ``share``, ``quality``,
+  ``subsampling``, ``restart_interval`` and ``geometries``, a list of
+  ``[width, height, weight]``; image *i* draws its kind by share and then
+  its geometry by weight from its own generator ``default_rng([seed, i])``,
+  which then makes its pixels. Where there is one choice nothing is drawn,
+  so the one-kind form gives the same bytes as it always has.
+
+``subsampling`` is ``gray`` (one component, luma tables, the frame's
+rounded BT.601 luma), ``4:4:4``, ``4:2:2`` or ``4:2:0``; ``tables`` can only
+be ``Annex K``. A key the generator cannot honour raises when the corpus
+starts.
 """
 from __future__ import annotations
 
@@ -152,21 +168,30 @@ def _segment(marker: int, payload: bytes) -> bytes:
     return bytes([0xFF, marker]) + (len(payload) + 2).to_bytes(2, "big") + payload
 
 
-def encode(img: np.ndarray, quality: int, subsampling: str) -> bytes:
-    """Baseline JFIF bytes of an (H, W, 3) uint8 image: BT.601 YCbCr, box
-    subsampling, float64 fDCT, Annex K tables at libjpeg ``quality``, one
-    interleaved scan, no restart markers."""
+def encode(img: np.ndarray, quality: int, subsampling: str,
+           restart_interval: int = 0) -> bytes:
+    """Baseline JFIF bytes of an (H, W, 3) uint8 image, or an (H, W) one
+    for ``gray``: BT.601 YCbCr, box subsampling, float64 fDCT, Annex K
+    tables at libjpeg ``quality``, one interleaved scan. Where
+    ``restart_interval`` > 0, a DRI segment, and every that many MCUs the
+    bits padded with ones, RST0-RST7 in turn, and the DC predictions reset
+    (T.81 F.1.1.5, F.1.2.3)."""
     factors = T.SUBSAMPLING[subsampling]
+    if (img.ndim == 2) != (len(factors) == 1):
+        raise ValueError(f"a {img.shape} image cannot be coded as {subsampling}")
     h_max = max(f[0] for f in factors)
     v_max = max(f[1] for f in factors)
     height, width = img.shape[:2]
     mcus_y, mcus_x = -(-height // (8 * v_max)), -(-width // (8 * h_max))
     ph, pw = mcus_y * 8 * v_max, mcus_x * 8 * h_max
-    rgb = img.astype(np.float64)
-    r, g, b = rgb[..., 0], rgb[..., 1], rgb[..., 2]
-    ycc = np.stack([0.299 * r + 0.587 * g + 0.114 * b,
-                    -0.168735892 * r - 0.331264108 * g + 0.5 * b + 128.0,
-                    0.5 * r - 0.418687589 * g - 0.081312411 * b + 128.0], -1)
+    if img.ndim == 2:
+        ycc = img.astype(np.float64)[..., None]
+    else:
+        rgb = img.astype(np.float64)
+        r, g, b = rgb[..., 0], rgb[..., 1], rgb[..., 2]
+        ycc = np.stack([0.299 * r + 0.587 * g + 0.114 * b,
+                        -0.168735892 * r - 0.331264108 * g + 0.5 * b + 128.0,
+                        0.5 * r - 0.418687589 * g - 0.081312411 * b + 128.0], -1)
     quant = (T.quality_quant(T.LUMA_QUANT, quality),
              T.quality_quant(T.CHROMA_QUANT, quality))
     upm = sum(h * v for h, v in factors)
@@ -192,27 +217,39 @@ def encode(img: np.ndarray, quality: int, subsampling: str) -> bytes:
             units = qc[by::fv, bx::fh].reshape(-1, 64)
             coeff[slot::upm] = units
             table_of_unit[slot::upm] = min(ci, 1)
-            # DC prediction per component, over its units in scan order
             slot += 1
-    for ci, (fh, fv) in enumerate(factors):
-        first = sum(h * v for h, v in factors[:ci])
-        cols = [first + i for i in range(fh * fv)]
-        mask = np.zeros(len(coeff), bool)
-        for c in cols:
-            mask[c::upm] = True
-        idx = np.where(mask)[0]
-        coeff[idx, 0] = np.diff(coeff[idx, 0], prepend=0)
-    # the symbol stream in runs of units, so that a worker's memory stays
-    # small at any frame size (each unit's symbols depend on it alone)
-    parts = [_symbol_stream(coeff[i:i + UNITS_PER_RUN],
-                            table_of_unit[i:i + UNITS_PER_RUN])
-             for i in range(0, len(coeff), UNITS_PER_RUN)]
-    scan = _pack(np.concatenate([v for v, _ in parts]),
-                 np.concatenate([n for _, n in parts]))
-    return _headers(width, height, factors, quant, quality) + scan + b"\xff\xd9"
+    # DC prediction per component, over its units in scan order, from 0 at
+    # the start of the scan and of each restart interval
+    n_units = len(coeff)
+    seg_units = restart_interval * upm if restart_interval else n_units
+    segment = np.arange(n_units) // seg_units
+    comp = np.tile(np.repeat(np.arange(len(factors)), [h * v for h, v in factors]),
+                   n_units // upm)
+    for ci in range(len(factors)):
+        idx = np.where(comp == ci)[0]
+        dc = coeff[idx, 0]
+        diff = np.diff(dc, prepend=0)
+        first = np.concatenate([[True], segment[idx][1:] != segment[idx][:-1]])
+        diff[first] = dc[first]
+        coeff[idx, 0] = diff
+    # each interval's symbol stream in runs of units, so that a worker's
+    # memory stays small at any frame size (each unit's symbols depend on it
+    # alone), packed on its own and followed by its marker
+    scan = bytearray()
+    for k, s in enumerate(range(0, n_units, seg_units)):
+        e = min(s + seg_units, n_units)
+        parts = [_symbol_stream(coeff[i:min(i + UNITS_PER_RUN, e)],
+                                table_of_unit[i:min(i + UNITS_PER_RUN, e)])
+                 for i in range(s, e, UNITS_PER_RUN)]
+        if k:
+            scan += bytes([0xFF, 0xD0 + (k - 1) % 8])
+        scan += _pack(np.concatenate([v for v, _ in parts]),
+                      np.concatenate([n for _, n in parts]))
+    return (_headers(width, height, factors, quant, restart_interval)
+            + bytes(scan) + b"\xff\xd9")
 
 
-def _headers(width, height, factors, quant, quality) -> bytes:
+def _headers(width, height, factors, quant, restart_interval) -> bytes:
     n_comp = len(factors)
     out = bytearray(b"\xff\xd8")
     out += _segment(0xE0, b"JFIF\x00" + bytes([1, 2, 0]) + (1).to_bytes(2, "big") * 2
@@ -229,6 +266,8 @@ def _headers(width, height, factors, quant, quality) -> bytes:
         bits, vals = T.STD_HUFFMAN[(kind, tid)]
         out += _segment(0xC4, bytes([(0 if kind == "dc" else 1) << 4 | tid])
                         + bytes(bits) + bytes(vals))
+    if restart_interval:
+        out += _segment(0xDD, restart_interval.to_bytes(2, "big"))
     sos = bytes([n_comp])
     for ci in range(n_comp):
         sos += bytes([ci + 1, (min(ci, 1) << 4) | min(ci, 1)])
@@ -236,13 +275,38 @@ def _headers(width, height, factors, quant, quality) -> bytes:
     return bytes(out)
 
 
-def _encode_frame(args) -> bytes:
-    """Pool task: frame ``i`` of a corpus (its own generator, so frames are
-    independent of how the pool splits them)."""
-    seed, i, width, height, quality, subsampling = args
+def gray(rgb: np.ndarray) -> np.ndarray:
+    """The (H, W) uint8 BT.601 luma of an RGB frame: a grayscale photo."""
+    rgb = rgb.astype(np.float64)
+    y = 0.299 * rgb[..., 0] + 0.587 * rgb[..., 1] + 0.114 * rgb[..., 2]
+    return np.clip(np.round(y), 0, 255).astype(np.uint8)
+
+
+def _draw(rng: np.random.Generator, weights) -> int:
+    """An index drawn by ``weights``; nothing is drawn from a single one."""
+    if len(weights) == 1:
+        return 0
+    p = np.asarray(weights, np.float64)
+    return int(rng.choice(len(p), p=p / p.sum()))
+
+
+def frame(seed: int, i: int, kinds: tuple):
+    """(kind, pixels) of frame ``i`` of a corpus of ``kinds`` (``corpus_spec``):
+    its own generator draws the kind, the geometry and the pixels, so that
+    frames are independent of how a pool splits them."""
     rng = np.random.default_rng([seed, i])
-    return encode(synth_frame(rng, width, height, t=0.13 * i), quality,
-                  subsampling)
+    kind = kinds[_draw(rng, [k[0] for k in kinds])]
+    geometries = kind[4]
+    width, height, _ = geometries[_draw(rng, [g[2] for g in geometries])]
+    img = synth_frame(rng, width, height, t=0.13 * i)
+    return kind, gray(img) if kind[2] == "gray" else img
+
+
+def _encode_frame(args) -> bytes:
+    """Pool task: the bytes of frame ``i`` of a corpus."""
+    seed, i, kinds = args
+    (_, quality, subsampling, restart, _), img = frame(seed, i, kinds)
+    return encode(img, quality, subsampling, restart)
 
 
 def digest() -> str:
@@ -255,9 +319,78 @@ def digest() -> str:
     return h.hexdigest()[:16]
 
 
+ONE_KIND_KEYS = {"width", "height", "quality", "subsampling", "restart_interval",
+                 "tables", "seed"}
+KINDS_KEYS = {"kinds", "tables", "seed"}
+KIND_KEYS = {"share", "quality", "subsampling", "restart_interval", "geometries"}
+TABLES = "Annex K"
+
+
+def _keys(d: dict, allowed: set, required: set, where: str) -> None:
+    if not isinstance(d, dict):
+        raise ValueError(f"{where} is not an object")
+    if set(d) - allowed:
+        raise ValueError(f"{where}: the generator cannot honour "
+                         f"{sorted(set(d) - allowed)}")
+    if required - set(d):
+        raise ValueError(f"{where}: {sorted(required - set(d))} missing")
+
+
+def _whole(x, lo: int, hi: int, where: str) -> int:
+    if isinstance(x, bool) or not isinstance(x, int) or not lo <= x <= hi:
+        raise ValueError(f"{where}: {x!r} is not a whole number in [{lo}, {hi}]")
+    return x
+
+
+def _weights(ws, where: str) -> None:
+    if not all(isinstance(w, (int, float)) and not isinstance(w, bool) and w > 0
+               for w in ws):
+        raise ValueError(f"{where}: every weight must be a number above 0")
+    if abs(sum(ws) - 1.0) > 1e-6:
+        raise ValueError(f"{where}: the weights sum to {sum(ws)!r}, not 1")
+
+
 def corpus_spec(config: dict) -> tuple:
+    """The configuration's corpus as a tuple of kinds, each ``(share,
+    quality, subsampling, restart_interval, ((width, height, weight), ...))``;
+    ValueError on anything the generator cannot honour."""
     c = config["corpus"]
-    return (c["width"], c["height"], c["quality"], c["subsampling"])
+    if isinstance(c, dict) and "kinds" in c:
+        _keys(c, KINDS_KEYS, {"kinds", "seed"}, "corpus")
+        kinds = c["kinds"]
+        if not isinstance(kinds, list) or not kinds:
+            raise ValueError("corpus.kinds: a list of one kind or more")
+    else:
+        _keys(c, ONE_KIND_KEYS, {"width", "height", "quality", "subsampling", "seed"},
+              "corpus")
+        kinds = [{"share": 1.0, "quality": c["quality"],
+                  "subsampling": c["subsampling"],
+                  "restart_interval": c.get("restart_interval", 0),
+                  "geometries": [[c["width"], c["height"], 1.0]]}]
+    if c.get("tables", TABLES) != TABLES:
+        raise ValueError(f"corpus.tables: only {TABLES!r} tables are written, "
+                         f"not {c['tables']!r}")
+    for n, k in enumerate(kinds):
+        _keys(k, KIND_KEYS, KIND_KEYS, f"corpus.kinds[{n}]")
+    _weights([k["share"] for k in kinds], "corpus.kinds shares")
+    out = []
+    for n, k in enumerate(kinds):
+        where = f"corpus.kinds[{n}]"
+        if k["subsampling"] not in T.SUBSAMPLING:
+            raise ValueError(f"{where}.subsampling: {k['subsampling']!r} is none of "
+                             f"{sorted(T.SUBSAMPLING)}")
+        geoms = k["geometries"]
+        if not isinstance(geoms, list) or not geoms or not all(
+                isinstance(g, list) and len(g) == 3 for g in geoms):
+            raise ValueError(f"{where}.geometries: a list of [width, height, weight]")
+        _weights([g[2] for g in geoms], f"{where}.geometries")
+        out.append((float(k["share"]), _whole(k["quality"], 1, 100, f"{where}.quality"),
+                    k["subsampling"],
+                    _whole(k["restart_interval"], 0, 65535, f"{where}.restart_interval"),
+                    tuple((_whole(w, 1, 65535, f"{where} width"),
+                           _whole(h, 1, 65535, f"{where} height"), float(p))
+                          for w, h, p in geoms)))
+    return tuple(out)
 
 
 def tagged(blob: bytes, tag: str) -> bytes:
@@ -288,14 +421,16 @@ class _Pending:
 
 def start(config: dict, pool) -> _Pending:
     """The ``n_images`` JPEGs of ``config``'s corpus: read from the cache,
-    or encoded in ``pool`` while the caller goes on."""
+    or encoded in ``pool`` while the caller goes on. Raises ValueError on a
+    corpus the generator cannot honour, before any work."""
+    kinds = corpus_spec(config)
     seed, n_images = config["corpus"]["seed"], config["n_images"]
-    key = hashlib.sha1(repr((config["name"], corpus_spec(config), seed,
+    key = hashlib.sha1(repr((config["name"], kinds, seed,
                              n_images, digest())).encode()).hexdigest()[:20]
     path = os.path.join(CACHE_DIR, f"{config['name']}_{key}.pkl")
     if os.path.exists(path):
         with open(path, "rb") as f:
             return _Pending(None, pickle.load(f), "read from the corpus cache")
-    tasks = [(seed, i) + corpus_spec(config) for i in range(n_images)]
+    tasks = [(seed, i, kinds) for i in range(n_images)]
     return _Pending(path, pool.map_async(_encode_frame, tasks, chunksize=1),
                     "encoded in the worker pool")

@@ -53,8 +53,10 @@ STD_HUFFMAN = {
                 tuple(_AC_CHROMA_VALS)),
 }
 
-# (h, v) sampling factors per component.
+# (h, v) sampling factors per component; "gray" is one component, coded
+# with the luma tables.
 SUBSAMPLING = {
+    "gray": ((1, 1),),
     "4:4:4": ((1, 1), (1, 1), (1, 1)),
     "4:2:2": ((2, 1), (1, 1), (1, 1)),
     "4:2:0": ((2, 2), (1, 1), (1, 1)),

@@ -3,8 +3,13 @@
 It parses the file, decodes the Huffman scan one symbol after another,
 reverses the DC prediction, and computes pixels in float64: dequantize,
 8x8 inverse DCT, level shift, round and clip each sample, replicate the
-chroma up to full size, convert BT.601 YCbCr to RGB, round and clip. It
-imports nothing of the program.
+chroma up to full size, convert BT.601 YCbCr to RGB, round and clip. A
+one-component (grayscale) file gives its (H, W) plane. It imports nothing
+of the program.
+
+``crop_resize`` makes a crop answer from that: the box cut from the
+image's RGB and resized (``RESIZE``: bilinear with half-pixel centres, no
+antialiasing, in float64, rounded once).
 
 ``pixels(..., precision=...)`` computes the same pixels at a lower
 precision (``PRECISIONS``):
@@ -282,7 +287,8 @@ def _idct(nat: np.ndarray, quant, precision: str, rnd) -> np.ndarray:
 
 
 def pixels(fr: Frame, coeff: np.ndarray, precision: str = "float64") -> np.ndarray:
-    """(H, W, 3) uint8 RGB from absolute zig-zag coefficients."""
+    """(H, W, 3) uint8 RGB from absolute zig-zag coefficients; (H, W) for
+    a one-component file."""
     if precision not in PRECISIONS:
         raise ValueError(f"unknown precision {precision!r}")
     rnd = _bf16 if precision == "bfloat16" else (lambda x: x)
@@ -300,6 +306,8 @@ def pixels(fr: Frame, coeff: np.ndarray, precision: str = "float64") -> np.ndarr
                  .reshape(my * v * 8, mx * h * 8))
         fy, fx = fr.v_max // v, fr.h_max // h
         planes.append(np.repeat(np.repeat(plane, fy, axis=0), fx, axis=1))
+    if len(planes) == 1:
+        return planes[0].astype(np.uint8)[:fr.height, :fr.width]
     y, cb, cr = planes[0], planes[1] - 128.0, planes[2] - 128.0
     rgb = np.stack([rnd(y + rnd(1.402 * cr)),
                     rnd(y - rnd(0.344136286 * cb) - rnd(0.714136286 * cr)),
@@ -312,3 +320,34 @@ def decode(data: bytes, precision: str = "float64"):
     """(coefficients, RGB) of one JPEG."""
     fr, coeff = coefficients(data)
     return coeff, pixels(fr, coeff, precision)
+
+
+RESIZE = "bilinear_half_pixel"
+
+
+def _taps(n_in: int, n_out: int):
+    """(lower index, upper index, weight of the upper) of each output sample
+    along one axis: half-pixel centres, the position clamped to the edge
+    samples."""
+    x = np.clip((np.arange(n_out) + 0.5) * (n_in / n_out) - 0.5, 0, n_in - 1)
+    lo = np.floor(x).astype(np.int64)
+    return lo, np.minimum(lo + 1, n_in - 1), x - lo
+
+
+def crop_resize(img: np.ndarray, crop) -> np.ndarray:
+    """The ``(top, left, height, width, out_height, out_width)`` crop of an
+    (H, W, 3) or (H, W) uint8 image as (out_height, out_width, 3) uint8 RGB:
+    the box resized by ``RESIZE``. A one-component image's RGB is its plane
+    in all three channels (Cb = Cr = 128)."""
+    top, left, h, w, oh, ow = (int(v) for v in crop)
+    if min(h, w, oh, ow) < 1 or top < 0 or left < 0 or top + h > img.shape[0] \
+            or left + w > img.shape[1]:
+        raise ValueError(f"crop {crop} does not fit a {img.shape} image")
+    box = img[top:top + h, left:left + w].astype(np.float64)
+    if box.ndim == 2:
+        box = np.repeat(box[..., None], 3, axis=-1)
+    y0, y1, fy = _taps(h, oh)
+    x0, x1, fx = _taps(w, ow)
+    rows = box[y0] * (1 - fy)[:, None, None] + box[y1] * fy[:, None, None]
+    out = rows[:, x0] * (1 - fx)[None, :, None] + rows[:, x1] * fx[None, :, None]
+    return np.clip(np.round(out), 0, 255).astype(np.uint8)

@@ -15,7 +15,10 @@ def roofline_pct(ctx, program: str, stage: str):
     batches = counters.get("batch_blobs")
     if seconds <= 0 or not batches or red.module_count(program) != len(batches):
         return None
-    images = [work.image_work(b) for batch in batches for b in batch]
+    # a driver whose answers are crops gives them per batch as batch_crops
+    crops = counters.get("batch_crops") or [[None] * len(b) for b in batches]
+    images = [work.image_work(b, c) for batch, cs in zip(batches, crops)
+              for b, c in zip(batch, cs)]
     w = _WORK[stage](images)
     least, _ = peaks.least_seconds(w["flops"], w["bytes"], ctx["device_kind"],
                                    chips=len(red.devices))

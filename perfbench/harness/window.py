@@ -13,7 +13,7 @@ measures for ``seconds``, under the profiler when ``trace_dir`` is set.
 from __future__ import annotations
 
 import dataclasses
-from typing import List
+from typing import List, Optional
 
 
 @dataclasses.dataclass
@@ -21,8 +21,11 @@ class Sample:
     """One answer of the window, kept for the comparison."""
     blob: bytes
     coeffs: object          # program's coefficients (device or host array) or None
-    rgb: object             # program's RGB, (H, W, 3)
+    rgb: object             # program's RGB, (H, W, 3), or (H, W) for grayscale
     missing: bool = False   # the answer never came
+    # (top, left, height, width, out_height, out_width): the answer is that
+    # box of the frame resized to (out_height, out_width, 3); None: the frame
+    crop: Optional[tuple] = None
 
 
 @dataclasses.dataclass

@@ -38,7 +38,8 @@ def main(argv):
                 cell, seed, args.seconds, False, t0, pool)
             row = {"seed": seed, "correct": result["correct"], "program": program}
             for c in parts[1:]:
-                row[c] = check.compare(samples, pool, control=c)
+                row[c] = check.compare(samples, pool, control=c,
+                                       resize=check.answers_spec(cell.config))
             row["metrics"] = {k: v["value"] for k, v in result["metrics"].items()}
             rows.append(row)
             print("READING " + json.dumps(row), flush=True)
