@@ -52,6 +52,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import decode as D
 from ..dist import sharding as S
@@ -62,8 +63,8 @@ from ..kernels.backend import (check_backend, check_fuse,
 from ..jpeg.format import parse_jpeg, segment_byte_bounds, unstuff_scan
 from .bitstream import (BatchPlan, BatchValidation, LADDER_STEP, PlanShape,
                         STATUS_OK, bucket_capacity, build_batch_plan,
-                        build_plan_data, consensus_plan, plan_shape,
-                        validate_batch)
+                        build_plan_data, consensus_plan, crop_layout,
+                        plan_shape, validate_batch)
 from .state import DecodeState
 from .sync import SyncResult, faithful_sync, jacobi_sync, specmap_sync
 
@@ -85,11 +86,15 @@ _LANE_KEYS = ("chunk_start", "chunk_limit", "chunk_seg", "chunk_seq",
 # Spans and phases, on the profiler's clock. Host spans are
 # ``jax.profiler.TraceAnnotation``s named ``repro.*`` that carry the
 # batch's process-wide id (``batch=``) and its counters; they nest on the
-# calling thread. The entropy program's two device phases are named scopes,
-# kept in its compiled HLO's ``op_name`` metadata (:func:`hlo_phases`).
+# calling thread. The entropy program's two device phases, and the crop's
+# tap gather and resize inside the pixel program, are named scopes, kept
+# in the compiled HLO's ``op_name`` metadata (:func:`hlo_phases`).
 # With the profiler off a span costs about a microsecond on the host.
 SYNC_PHASE = "repro.entropy.sync"
 WRITE_PHASE = "repro.entropy.write"
+CROP_PHASE = "repro.pixels.crop"
+# the crop a training step takes by default (ResNet/ViT input size)
+CROP_SIZE = (224, 224)
 _BATCH_IDS = itertools.count()
 
 
@@ -97,18 +102,20 @@ def _span(name: str, batch: int, **counters):
     return jax.profiler.TraceAnnotation(name, batch=batch, **counters)
 
 
-def hlo_phases(hlo_text: str) -> Dict[str, str]:
-    """``{entry-level instruction name: phase}`` of a compiled entropy
-    program's HLO text, for the instructions whose ``op_name`` lies under
-    :data:`SYNC_PHASE` or :data:`WRITE_PHASE`. Names are unique within a
-    module and an entry-level instruction's device time covers everything
+def hlo_phases(hlo_text: str,
+               phases: Sequence[str] = (SYNC_PHASE, WRITE_PHASE)
+               ) -> Dict[str, str]:
+    """``{entry-level instruction name: phase}`` of a compiled program's HLO
+    text, for the instructions whose ``op_name`` lies under one of
+    ``phases`` (the entropy program's by default). Names are unique within
+    a module and an entry-level instruction's device time covers everything
     nested in it, so summing the mapped instructions counts no time twice."""
     entry = hlo_text[hlo_text.index("\nENTRY ") + 1:].splitlines()[1:]
     out = {}
     for line in itertools.takewhile(lambda x: x != "}", entry):
         name = line.strip().removeprefix("ROOT ").split(" = ", 1)[0]
         scopes = line.partition('op_name="')[2].partition('"')[0].split("/")
-        for phase in (SYNC_PHASE, WRITE_PHASE):
+        for phase in phases:
             if phase in scopes:
                 out[name.lstrip("%")] = phase
     return out
@@ -175,6 +182,7 @@ class DecodeOutput:
     # plan.unit_valid)
     status: Optional[object] = None     # (B,) int32 np.ndarray or None
     validation: Optional[BatchValidation] = None
+    crop: Optional[Array] = None        # (B, out_h, out_w, 3) uint8, emit="crop"
 
 
 def _sequential_chunk_bits(unstuffed, bucket: bool = True) -> int:
@@ -214,9 +222,12 @@ class DecodeProgram:
     batch's padded :class:`PlanData` operands (``words`` donated — it is
     the one buffer that is fresh every batch) and returns capacity-sized
     coefficients plus sync diagnostics. ``pixels_fn`` (uniform shapes only)
-    is the IDCT/color stage. Both are shared by every decoder whose batch
+    is the IDCT/color stage. ``crop_fn`` is the crop stage of any batch,
+    uniform or mixed, whose per-image layout and boxes are operands and
+    whose output size is static. All are shared by every decoder whose batch
     lands in this bucket; ``coeffs_traces``/``pixels_traces`` count actual
-    jax traces (incremented from inside the traced python body), which is
+    jax traces (incremented from inside the traced python body; a crop
+    program counts as a pixel program), which is
     how the compile-once guarantee is asserted in tests and surfaced in
     pipeline/benchmark stats.
     """
@@ -260,6 +271,15 @@ class DecodeProgram:
     # which device_phases() lowers the program again
     coeffs_args: Optional[tuple] = dataclasses.field(
         default=None, repr=False, compare=False)
+    # the IDCT the pixel and crop stages run, the crop stage, and the
+    # abstract arguments of its first call per (output size, trace_token),
+    # from which pixel_phases() lowers it again
+    idct_impl: object = dataclasses.field(default=None, repr=False,
+                                          compare=False)
+    crop_fn: object = dataclasses.field(default=None, repr=False,
+                                        compare=False)
+    crop_args: dict = dataclasses.field(default_factory=dict, repr=False,
+                                        compare=False)
 
     @property
     def compiles(self) -> int:
@@ -305,6 +325,29 @@ class DecodeProgram:
         return self._call_once_locked(("pixels", trace_token),
                                       self.pixels_fn, pixdev, pix_layout,
                                       coeffs, trace_token)
+
+    def call_crop(self, out_size, cropdev, coeffs, boxes, trace_token):
+        """``crop_fn`` with the first call per (out_size, trace_token)
+        serialized, as :meth:`call_coeffs`."""
+        key = (out_size, trace_token)
+        if key not in self.crop_args:
+            self.crop_args[key] = (_abstract(cropdev), _abstract(coeffs),
+                                   _abstract(boxes))
+        return self._call_once_locked(("crop",) + key, self.crop_fn, cropdev,
+                                      coeffs, boxes, out_size, trace_token)
+
+    def pixel_phases(self) -> Dict[str, str]:
+        """``{entry-level instruction: CROP_PHASE}`` of every crop program
+        this bucket ran, lowered again as :meth:`device_phases` does; ``{}``
+        before the first crop call."""
+        out: Dict[str, str] = {}
+        for (size, token), (cropdev, coeffs, boxes) in list(
+                self.crop_args.items()):
+            with _trace_context(token):
+                compiled = self.crop_fn.lower(
+                    cropdev, coeffs, boxes, size, token).compile()
+            out.update(hlo_phases(compiled.as_text(), (CROP_PHASE,)))
+        return out
 
 
 _PROGRAMS: Dict[Tuple, DecodeProgram] = {}
@@ -369,7 +412,8 @@ def decode_program(shape: PlanShape, sync: str = "jacobi",
     custom = DecodeProgram(shape=shape, sync=sync, backend=backend,
                            interpret=interpret, fuse=fuse, tiles=tiles,
                            coeffs_fn=prog.coeffs_fn,
-                           step_staged=prog.step_staged)
+                           step_staged=prog.step_staged, idct_impl=idct_impl)
+    custom.crop_fn = _build_crop_fn(custom)
     if shape.uniform:
         custom.pixels_fn = _build_pixels_fn(shape, idct_impl, custom)
     return custom
@@ -431,6 +475,7 @@ def _build_program(shape: PlanShape, sync: str, backend: str,
             idct_units, tile=tiles.unit_tile if tiles is not None else None,
             interpret=interpret)
     idct_impl = idct_impl or D.idct_units_folded
+    prog.idct_impl = idct_impl
     sh = shape
     # static at trace time: identity plans (the default) keep the old
     # shift/direct-scan lowerings; permuted plans use the chunk_prev /
@@ -550,6 +595,7 @@ def _build_program(shape: PlanShape, sync: str, backend: str,
         return coeffs, res.rounds, res.converged
 
     prog.coeffs_fn = _coeffs
+    prog.crop_fn = _build_crop_fn(prog)
 
     if sh.uniform:
         prog.pixels_fn = _build_pixels_fn(sh, idct_impl, prog, fuse=fuse,
@@ -615,6 +661,36 @@ def _build_pixels_fn(sh: PlanShape, idct_impl, prog: DecodeProgram,
         return _pixels_unfused(pixdev, pix_layout, coeffs)
 
     return _pixels
+
+
+def _build_crop_fn(prog: DecodeProgram):
+    """The jitted crop stage of ``prog``'s bucket: the IDCT of every unit
+    (capacity-sized, so that one program serves every batch of the
+    bucket), then :func:`repro.core.decode.crop_resize_units`'s tap gather
+    and resize to the static ``out_size`` under :data:`CROP_PHASE`, in the
+    one ``jit__pixels`` execution per batch the uniform stage has too. ``cropdev`` holds ``m_matrices``, ``unit_mrow`` and the per-image
+    ``layout`` (:func:`repro.core.bitstream.crop_layout`)."""
+    @functools.partial(jax.jit, static_argnums=(3, 4))
+    def _pixels(cropdev: Dict[str, Array], coeffs: Array, boxes: Array,
+                out_size: Tuple[int, int], trace_token):
+        prog.pixels_traces += 1
+        out_h, out_w = out_size
+        coeffs = S.shard(coeffs, "units", None)
+        pixels = prog.idct_impl(coeffs, cropdev["m_matrices"],
+                                cropdev["unit_mrow"])
+        with jax.named_scope(CROP_PHASE):
+            return D.crop_resize_units(pixels, cropdev["layout"], boxes,
+                                       out_h, out_w)
+
+    return _pixels
+
+
+def _check_out_size(out_size) -> Tuple[int, int]:
+    size = tuple(int(v) for v in out_size)
+    if len(size) != 2 or min(size) < 1:
+        raise ValueError(f"out_size must be (height, width) >= 1, got "
+                         f"{out_size!r}")
+    return size
 
 
 def _shape_covers(shape: PlanShape, plan: BatchPlan) -> bool:
@@ -737,9 +813,12 @@ class ParallelDecoder:
         with _span("repro.upload", self.batch_id):
             self._dev_rest = {k: jnp.asarray(v)
                               for k, v in self.data.arrays.items()}
+            self._pixdev = {"m_matrices": self._dev_rest["m_matrices"],
+                            "unit_mrow": self._dev_rest["unit_mrow"]}
+            # the crop stage's operands: _pixdev and the per-image layout,
+            # uploaded on the first crop decode
+            self._cropdev: Optional[Dict[str, Array]] = None
             if plan.uniform:
-                self._pixdev = {"m_matrices": self._dev_rest["m_matrices"],
-                                "unit_mrow": self._dev_rest["unit_mrow"]}
                 self._pix_layout = {
                     "comp_unit_idx": [jnp.asarray(a)
                                       for a in plan.comp_unit_idx],
@@ -901,12 +980,45 @@ class ParallelDecoder:
                        validation=validation, fuse=fuse, tiles=tiles,
                        batch_id=batch)
 
+    def _crop_boxes(self, crops=None) -> np.ndarray:
+        """(n_images of the bucket, 4) int32 (top, left, height, width):
+        ``crops`` (one box per image of the batch, in frame pixels) checked
+        against each frame, or each whole frame where ``crops`` is None.
+        Quarantined images, and rows past the batch, get a 1x1 box."""
+        geoms = self.plan.image_geometry or ()
+        boxes = np.tile(np.array([0, 0, 1, 1], np.int64),
+                        (self.shape.n_images, 1))
+        given = None
+        if crops is not None:
+            given = np.asarray(crops)
+            if (given.shape != (len(geoms), 4)
+                    or not np.issubdtype(given.dtype, np.integer)):
+                raise ValueError(
+                    f"crops: one (top, left, height, width) box of integers "
+                    f"per image, shape ({len(geoms)}, 4); got "
+                    f"{given.dtype} {given.shape}")
+        for i, g in enumerate(geoms):
+            if g is None:
+                continue
+            if given is None:
+                boxes[i] = (0, 0, g.height, g.width)
+                continue
+            top, left, h, w = (int(v) for v in given[i])
+            if (min(top, left) < 0 or min(h, w) < 1 or top + h > g.height
+                    or left + w > g.width):
+                raise ValueError(f"crop {i}: box {(top, left, h, w)} does "
+                                 f"not fit its {g.width}x{g.height} frame")
+            boxes[i] = (top, left, h, w)
+        return boxes.astype(np.int32)
+
     # -- execution ------------------------------------------------------------
     def coefficients(self) -> DecodeOutput:
         with _span("repro.decode", self.batch_id):
-            return self._coefficients()
+            return self._coefficients()[0]
 
-    def _coefficients(self) -> DecodeOutput:
+    def _coefficients(self) -> Tuple[DecodeOutput, Array]:
+        """(the output with the real coefficients, the capacity-sized
+        coefficients the crop stage reads)."""
         # numpy in => jit transfers a fresh device buffer it may donate;
         # the capacity-sized output is sliced to the real unit count
         # host-side (a python int, so no retrace)
@@ -915,8 +1027,9 @@ class ParallelDecoder:
                    lanes=shape.n_chunks, lanes_live=plan.n_chunks,
                    units=plan.total_units, units_cap=shape.n_units,
                    step=self.program.step):
-            coeffs, rounds, conv = self.program.call_coeffs(
+            coeffs_cap, rounds, conv = self.program.call_coeffs(
                 self.data.words, self._dev_rest, S.trace_token())
+        coeffs = coeffs_cap
         if coeffs.shape[0] != plan.total_units:
             with _span("repro.slice", batch):
                 coeffs = _slice_units(coeffs, plan.total_units,
@@ -927,13 +1040,28 @@ class ParallelDecoder:
             span.set_metadata(rounds=rounds)
         return DecodeOutput(coeffs, None, None, rounds, conv, plan,
                             status=plan.image_status,
-                            validation=self.validation)
+                            validation=self.validation), coeffs_cap
 
-    def decode(self, emit: str = "rgb") -> DecodeOutput:
+    def decode(self, emit: str = "rgb", crops=None,
+               out_size: Tuple[int, int] = CROP_SIZE) -> DecodeOutput:
+        """Decode the batch. ``emit``: "coeffs"; "rgb" (uniform batches)
+        or "planes"; or "crop", for any batch: ``DecodeOutput.crop`` is
+        (B, out_h, out_w, 3) uint8, each image's ``crops`` box (top,
+        left, height, width in frame pixels, checked against its frame;
+        None: the whole frame) resized to ``out_size``
+        by bilinear interpolation with half-pixel centres, the taps
+        clamped to the box, no antialiasing, rounded once, from the RGB
+        the uniform path computes (a gray frame's plane in all three
+        channels, a quarantined image's crop zero)."""
+        if emit == "crop":
+            out_size = _check_out_size(out_size)
+            boxes = self._crop_boxes(crops)
         with _span("repro.decode", self.batch_id):
-            out = self._coefficients()
+            out, coeffs_cap = self._coefficients()
             if emit == "coeffs":
                 return out
+            if emit == "crop":
+                return self._crop(out, coeffs_cap, boxes, out_size)
             if not self.plan.uniform:
                 if self.plan.image_status is not None:
                     # validated decode: a batch can lose pixel-stage
@@ -946,7 +1074,10 @@ class ParallelDecoder:
                     "images with mixed geometry via bucketing in "
                     "repro.data.jpeg_pipeline"
                 )
-            with _span("repro.dispatch.pixels", self.batch_id):
+            with _span("repro.dispatch.pixels", self.batch_id,
+                       layout="uniform", crops=0,
+                       units=self.plan.total_units,
+                       units_cap=self.shape.n_units):
                 planes, rgb = self.program.call_pixels(
                     self._pixdev, self._pix_layout, out.coeffs,
                     S.trace_token())
@@ -954,8 +1085,24 @@ class ParallelDecoder:
             out, planes=planes, rgb=rgb if emit == "rgb" else None
         )
 
+    def _crop(self, out: DecodeOutput, coeffs_cap: Array, boxes: np.ndarray,
+              out_size: Tuple[int, int]) -> DecodeOutput:
+        plan = self.plan
+        with _span("repro.dispatch.pixels", self.batch_id,
+                   layout="uniform" if plan.uniform else "mixed",
+                   crops=plan.n_images, units=plan.total_units,
+                   units_cap=self.shape.n_units):
+            if self._cropdev is None:
+                self._cropdev = dict(self._pixdev, layout=jnp.asarray(
+                    crop_layout(plan, self.shape.n_images)))
+            crop = self.program.call_crop(out_size, self._cropdev,
+                                          coeffs_cap, jnp.asarray(boxes),
+                                          S.trace_token())
+        return dataclasses.replace(out, crop=crop)
+
     def decode_on(self, mesh, emit: str = "rgb",
-                  rules: Optional[Dict] = None) -> DecodeOutput:
+                  rules: Optional[Dict] = None, crops=None,
+                  out_size: Tuple[int, int] = CROP_SIZE) -> DecodeOutput:
         """Decode with chunk lanes and output units sharded over the mesh's
         data axis — the multi-device batch-decode path. Bit-identical to
         :meth:`decode`; only the work placement changes.
@@ -986,7 +1133,7 @@ class ParallelDecoder:
         if rules is None:
             rules = _decode_rules(mesh)
         with mesh, S.logical_rules(rules):
-            return self.decode(emit=emit)
+            return self.decode(emit=emit, crops=crops, out_size=out_size)
 
 
 def _entries_from(dev, exits: DecodeState, permuted: bool = True) -> DecodeState:
@@ -1009,8 +1156,14 @@ def decode_batch(
     bucket: bool = True,
     validate: bool = False,
     fuse: Optional[str] = None,
+    crops=None,
+    out_size: Tuple[int, int] = CROP_SIZE,
 ) -> DecodeOutput:
     """One-shot convenience wrapper (builds the plan + compiles + decodes).
+
+    ``emit="crop"`` gives each image's ``crops`` box resized to
+    ``out_size`` for any batch, mixed geometries included
+    (:meth:`ParallelDecoder.decode`).
 
     With ``mesh``, the decode runs under ``dist.sharding.logical_rules``
     with the chunk lanes sharded over the data axis: one compiled program,
@@ -1041,5 +1194,5 @@ def decode_batch(
         bucket=bucket, validate=validate, fuse=fuse,
     )
     if mesh is None:
-        return dec.decode(emit=emit)
-    return dec.decode_on(mesh, emit=emit)
+        return dec.decode(emit=emit, crops=crops, out_size=out_size)
+    return dec.decode_on(mesh, emit=emit, crops=crops, out_size=out_size)

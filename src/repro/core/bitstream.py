@@ -347,6 +347,11 @@ class BatchPlan:
     # independently so the per-device layout survives bucketing.
     n_lanes: int = 1
 
+    # --- per image (crop stage, any batch) ---------------------------------------
+    # each image's geometry, None for a quarantined one; crop_layout turns
+    # it into device data, so it never enters PlanShape
+    image_geometry: Optional[Tuple[Optional[ImageGeometry], ...]] = None
+
     # --- resilience (host-side, set when planned from a BatchValidation) ------
     # These never ship to the device and never enter PlanShape — quarantine
     # is pure PlanData (zero-bit segments), so it cannot mint compile keys.
@@ -694,6 +699,44 @@ def build_plan_data(plan: BatchPlan, shape: PlanShape) -> PlanData:
         n_chunks=plan.n_chunks, n_sequences=plan.n_sequences,
         total_units=plan.total_units,
     )
+
+
+# Columns of the crop stage's per-image layout (crop_layout): where the
+# image's units start in the coefficient array, its MCU row pitch, whether
+# it has a pixel layout at all and whether it is grayscale, then for each of
+# three components (a gray frame repeats its one) its sampling factors, its
+# first unit slot within the MCU and its replication factors up to the
+# frame's sampling grid.
+LAYOUT_IMAGE = ("unit_base", "mcus_x", "upm", "live", "gray")
+LAYOUT_COMP = ("h", "v", "slot", "fy", "fx")
+LAYOUT_COLS = len(LAYOUT_IMAGE) + 3 * len(LAYOUT_COMP)
+
+
+def crop_layout(plan: BatchPlan, n_images: int) -> np.ndarray:
+    """(n_images, LAYOUT_COLS) int32: each image's pixel layout as data, so
+    that one crop program serves every batch of a capacity bucket whatever
+    its geometries. Rows past the plan's images, and quarantined images,
+    are dead (``live`` 0)."""
+    # a dead row reads unit 0 with unit factors (no division by zero) and
+    # its crop is zeroed
+    out = np.tile(np.array([0, 1, 1, 0, 0] + [1, 1, 0, 1, 1] * 3, np.int32),
+                  (n_images, 1))
+    geoms = plan.image_geometry or ()
+    bases = np.searchsorted(plan.unit_image, np.arange(len(geoms)))
+    for i, (g, base) in enumerate(zip(geoms, bases)):
+        if g is None:
+            continue
+        if g.n_components not in (1, 3):
+            raise ValueError(f"image {i} has {g.n_components} components: the "
+                             f"crop stage decodes gray and YCbCr frames only")
+        comps =list(range(g.n_components)) if g.n_components == 3 else [0] * 3
+        slots = np.concatenate([[0], np.cumsum(np.multiply(g.comp_h, g.comp_v))])
+        row = [base, g.mcus_x, g.units_per_mcu, 1, int(g.n_components == 1)]
+        for c in comps:
+            row += [g.comp_h[c], g.comp_v[c], slots[c],
+                    g.v_max // g.comp_v[c], g.h_max // g.comp_h[c]]
+        out[i] = row
+    return out
 
 
 def split_plan(plan: BatchPlan, bucket: bool = True,
@@ -1228,6 +1271,8 @@ def build_batch_plan(
         comp_unit_idx=comp_unit_idx,
         comp_block_idx=comp_block_idx,
         comp_grid=comp_grid,
+        image_geometry=tuple(ImageGeometry.of(img) if img is not None
+                             else None for img in images),
         image_status=(validation.status if validation is not None else None),
         seg_valid=(np.concatenate(seg_valid_l)
                    if validation is not None else None),

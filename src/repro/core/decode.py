@@ -429,3 +429,70 @@ def upsample_color(planes, comp_h, comp_v, h_max, v_max, height, width):
     rgb = jnp.stack([r, g, b], axis=-1)
     rgb = jnp.clip(jnp.round(rgb), 0, 255).astype(jnp.uint8)
     return rgb[:, :height, :width]
+
+
+# ---------------------------------------------------------------------------
+# Crop stage: any batch's frames to fixed-size crops, the layout as data
+# ---------------------------------------------------------------------------
+
+def crop_taps(n_in: jnp.ndarray, n_out: int):
+    """Bilinear taps along one axis of boxes ``n_in`` (B,) samples long,
+    resized to ``n_out``: (lower, upper, weight of the upper), each (B,
+    n_out). Half-pixel centres, the position clamped to the box's edge
+    samples, no antialiasing. The position ((2i + 1) n_in - n_out) /
+    (2 n_out) is floored in integers, so only the weight rounds."""
+    den = 2 * n_out
+    n_in = n_in[:, None]
+    num = (2 * jnp.arange(n_out, dtype=jnp.int32) + 1)[None, :] * n_in - n_out
+    num = jnp.clip(num, 0, (n_in - 1) * den)
+    lo = num // den
+    return (lo, jnp.minimum(lo + 1, n_in - 1),
+            (num - lo * den).astype(jnp.float32) / den)
+
+
+def crop_resize_units(pixels: jnp.ndarray, layout: jnp.ndarray,
+                      boxes: jnp.ndarray, out_h: int, out_w: int):
+    """(U, 64) row-major block pixels of any batch -> (B, out_h, out_w, 3)
+    uint8 crops.
+
+    ``layout`` is :func:`repro.core.bitstream.crop_layout`'s (B, cols)
+    per-image layout and ``boxes`` (B, 4) int32 (top, left, height, width)
+    in frame pixels, both device data. Each output sample is the frame's
+    RGB as :func:`upsample_color` computes it (chroma replicated, float32
+    conversion, rounded; a gray frame's plane in all three channels) at the
+    box's 2x2 bilinear taps, mixed and rounded once. Only the tap samples
+    are gathered: the row and column parts of each flat index are computed
+    apart, per component, and added.
+    """
+    from .bitstream import LAYOUT_COMP, LAYOUT_IMAGE
+    img = {k: layout[:, i][:, None] for i, k in enumerate(LAYOUT_IMAGE)}
+    y0, y1, wy = crop_taps(boxes[:, 2], out_h)
+    x0, x1, wx = crop_taps(boxes[:, 3], out_w)
+    ys = boxes[:, :1] + jnp.concatenate([y0, y1], axis=1)   # (B, 2 out_h)
+    xs = boxes[:, 1:2] + jnp.concatenate([x0, x1], axis=1)  # (B, 2 out_w)
+    idx = []
+    for c in range(3):
+        k = len(LAYOUT_IMAGE) + c * len(LAYOUT_COMP)
+        h, v, slot, fy, fx = (layout[:, k + j][:, None]
+                              for j in range(len(LAYOUT_COMP)))
+        yc, xc = ys // fy, xs // fx           # the component's own samples
+        row = ((img["unit_base"] + (yc // (8 * v)) * img["mcus_x"] * img["upm"]
+                + slot + (yc // 8 % v) * h) * 64 + yc % 8 * 8)
+        col = ((xc // (8 * h)) * img["upm"] + xc // 8 % h) * 64 + xc % 8
+        idx.append(row[:, :, None] + col[:, None, :])
+    ycc = jnp.take(pixels.reshape(-1), jnp.stack(idx), mode="clip")
+    gray = (img["gray"] == 1)[:, :, None]
+    y = ycc[0]
+    cb = jnp.where(gray, 0.0, ycc[1] - 128.0)
+    cr = jnp.where(gray, 0.0, ycc[2] - 128.0)
+    rgb = jnp.stack([y + 1.402 * cr,
+                     y - 0.344136286 * cb - 0.714136286 * cr,
+                     y + 1.772 * cb])
+    rgb = jnp.clip(jnp.round(rgb), 0, 255)  # (3, B, 2 out_h, 2 out_w)
+    wy = wy[None, :, :, None]
+    wx = wx[None, :, None, :]
+    rows = rgb[:, :, :out_h] * (1 - wy) + rgb[:, :, out_h:] * wy
+    out = rows[..., :out_w] * (1 - wx) + rows[..., out_w:] * wx
+    out = jnp.clip(jnp.round(out), 0, 255).astype(jnp.uint8)
+    out = jnp.where((img["live"] == 1)[None, :, :, None], out, 0)
+    return out.transpose(1, 2, 3, 0)

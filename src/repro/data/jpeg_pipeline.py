@@ -6,8 +6,13 @@ and patching all run on the accelerators, then feed the model's vision
 frontend directly.
 
 Pipeline: jpeg bytes --(host: parse+frame)--> device plan
-          --(device: parallel decode)--> RGB planes
+          --(device: parallel decode)--> RGB planes, or 224x224 crops
           --(device: patchify + linear embed stub)--> (B, n_patches, 1024)
+
+A batch of one geometry is patched whole; a batch of mixed geometries,
+or any batch given crop boxes, is decoded to fixed-size crops on the
+device (``ParallelDecoder.decode(emit="crop")``), so every image yields
+the same number of tokens.
 
 The host work is exactly the paper's host share (header parse + subsequence
 framing); pixels never exist host-side.
@@ -38,6 +43,7 @@ import numpy as np
 
 from ..core import (ParallelDecoder, STATUS_OK, STATUS_RECOVERED,
                     STATUS_REJECTED)
+from ..core.api import CROP_SIZE
 from ..jpeg.encoder import Dataset
 
 
@@ -60,6 +66,18 @@ class JpegPipelineStats:
     @property
     def transfer_saving(self) -> float:
         return self.decoded_mb / max(self.compressed_mb, 1e-9)
+
+
+def _centre_boxes(dec: ParallelDecoder) -> np.ndarray:
+    """The centre square of each frame's shorter side, as crop boxes (a
+    quarantined image's box is never read)."""
+    geoms = dec.plan.image_geometry or ()
+    boxes = np.zeros((len(geoms), 4), np.int64)
+    for i, g in enumerate(geoms):
+        if g is not None:
+            side = min(g.width, g.height)
+            boxes[i] = ((g.height - side) // 2, (g.width - side) // 2, side, side)
+    return boxes
 
 
 class JpegVisionPipeline:
@@ -179,31 +197,35 @@ class JpegVisionPipeline:
                     self._decoders.popitem(last=False)
         return dec
 
-    def patches_for(self, blobs: Sequence[bytes]):
-        """(B, n_patches, embed_dim) patch tokens + stats."""
+    def patches_for(self, blobs: Sequence[bytes], crops=None):
+        """(B, n_patches, embed_dim) patch tokens + stats.
+
+        A batch of one geometry without ``crops`` is patched whole. Any
+        other batch is decoded to ``CROP_SIZE`` crops on the device: each
+        image's ``crops`` box (top, left, height, width), or by default the
+        centre square of its shorter side. A quarantined image's crop is
+        zero, so its tokens are too, and ``status`` says why."""
         t0 = time.perf_counter()
         dec = self._decoder(blobs)
         self._last_dec = dec
         compiles_before = dec.program.compiles
+        if crops is None and dec.plan.uniform:
+            kw = dict(emit="rgb")
+        else:
+            kw = dict(emit="crop", out_size=CROP_SIZE,
+                      crops=crops if crops is not None else _centre_boxes(dec))
         if self.mesh is not None:
-            out = dec.decode_on(self.mesh, emit="rgb")
+            out = dec.decode_on(self.mesh, **kw)
         else:
-            out = dec.decode(emit="rgb")
-        rgb = out.rgb  # (B, H, W, 3) uint8 on device
+            out = dec.decode(**kw)
+        rgb = out.rgb if kw["emit"] == "rgb" else out.crop  # (B, H, W, 3)
         p = self.patch
-        if rgb is None:
-            # validated decode with no pixel stage (every image quarantined,
-            # or mixed-geometry survivors): emit zero patch tokens per image
-            # so the stream keeps flowing — status tells the caller why
-            b, h, w = len(blobs), 0, 0
-            tokens = jnp.zeros((b, 0, self.embed_dim), dtype=jnp.bfloat16)
-        else:
-            b, h, w, _ = rgb.shape
-            hc, wc = h // p, w // p
-            x = rgb[:, : hc * p, : wc * p].astype(jnp.bfloat16) / 255.0
-            x = x.reshape(b, hc, p, wc, p, 3).transpose(0, 1, 3, 2, 4, 5)
-            x = x.reshape(b, hc * wc, p * p * 3)
-            tokens = x @ self.w_embed
+        b, h, w, _ = rgb.shape
+        hc, wc = h // p, w // p
+        x = rgb[:, : hc * p, : wc * p].astype(jnp.bfloat16) / 255.0
+        x = x.reshape(b, hc, p, wc, p, 3).transpose(0, 1, 3, 2, 4, 5)
+        x = x.reshape(b, hc * wc, p * p * 3)
+        tokens = x @ self.w_embed
         if self.sync_stats:
             jax.block_until_ready(tokens)
         dt_ms = (time.perf_counter() - t0) * 1e3

@@ -590,3 +590,37 @@ def decode_baseline(data: bytes) -> np.ndarray:
     coeff = undiff_dc(img, coeff)
     planes = coefficients_to_planes(img, coeff)
     return upsample_and_color(img, planes)
+
+
+# ---------------------------------------------------------------------------
+# Crop and resize (the oracle of the decoder's emit="crop")
+# ---------------------------------------------------------------------------
+
+def _bilinear_taps(n_in: int, n_out: int):
+    """(lower, upper, weight of the upper) per output sample along one
+    axis: half-pixel centres, the position clamped to the edge samples."""
+    x = np.clip((np.arange(n_out) + 0.5) * (n_in / n_out) - 0.5, 0, n_in - 1)
+    lo = np.floor(x).astype(np.int64)
+    return lo, np.minimum(lo + 1, n_in - 1), x - lo
+
+
+def crop_resize(img: np.ndarray, box, out_size) -> np.ndarray:
+    """The ``box`` = (top, left, height, width) of a decoded frame
+    (:func:`decode_baseline`'s (H, W, 3) RGB, or (H, W) gray plane, which
+    counts as its plane in all three channels) resized to ``out_size`` =
+    (out_height, out_width) as (out_height, out_width, 3) uint8: bilinear
+    with half-pixel centres, the taps clamped to the box, no antialiasing,
+    in float64, rounded once."""
+    top, left, h, w = (int(v) for v in box)
+    oh, ow = (int(v) for v in out_size)
+    if (min(h, w, oh, ow) < 1 or min(top, left) < 0
+            or top + h > img.shape[0] or left + w > img.shape[1]):
+        raise ValueError(f"box {box} does not fit a {img.shape} frame")
+    crop = img[top:top + h, left:left + w].astype(np.float64)
+    if crop.ndim == 2:
+        crop = np.repeat(crop[..., None], 3, axis=-1)
+    y0, y1, fy = _bilinear_taps(h, oh)
+    x0, x1, fx = _bilinear_taps(w, ow)
+    rows = crop[y0] * (1 - fy)[:, None, None] + crop[y1] * fy[:, None, None]
+    out = rows[:, x0] * (1 - fx)[None, :, None] + rows[:, x1] * fx[None, :, None]
+    return np.clip(np.round(out), 0, 255).astype(np.uint8)

@@ -1,6 +1,7 @@
 """Integration: the paper's decoder as the VLM input pipeline."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.data.jpeg_pipeline import JpegVisionPipeline
 from repro.jpeg.encoder import DatasetSpec, build_dataset
@@ -150,3 +151,32 @@ def test_paper_datasets_registry():
     # quality ladder ordering preserved
     assert (PAPER_DATASETS["tos_8"].quality > PAPER_DATASETS["tos_14"].quality
             > PAPER_DATASETS["tos_20"].quality)
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["centre", "given"])
+def test_mixed_batch_gives_tokens_of_its_crops(given):
+    """A mixed batch is decoded to 224x224 crops: by default the centre
+    square of each frame's shorter side, or the boxes given; its tokens are
+    those of the crop batch, 196 per image at patch 16."""
+    from repro.core import decode_batch
+    from repro.jpeg import codec_ref as cr
+    imgs = [(48, 64, "4:2:0"), (40, 24, "4:4:4"), (33, 45, "gray")]
+    rng = np.random.default_rng(4)
+    blobs = []
+    for h, w, s in imgs:
+        img = rng.integers(0, 256, (h, w, 3)).astype(np.uint8)
+        blobs.append(cr.encode_baseline(img[..., 0] if s == "gray" else img,
+                                        quality=85, subsampling=s).jpeg_bytes)
+    boxes = (np.array([[4, 2, 30, 40], [0, 0, 40, 24], [1, 3, 20, 20]])
+             if given else
+             np.array([[0, 8, 48, 48], [8, 0, 24, 24], [0, 6, 33, 33]]))
+    pipe = JpegVisionPipeline(patch=16, embed_dim=32, chunk_bits=256)
+    tokens, stats = pipe.patches_for(blobs, crops=boxes if given else None)
+    assert tokens.shape == (3, 196, 32)
+    crop = decode_batch(blobs, chunk_bits=256, emit="crop", crops=boxes).crop
+    x = (np.asarray(crop, np.float32) / 255.0).reshape(3, 14, 16, 14, 16, 3)
+    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(3, 196, 768)
+    exp = jnp.asarray(x, jnp.bfloat16) @ pipe.w_embed
+    np.testing.assert_array_equal(np.asarray(tokens, np.float32),
+                                  np.asarray(exp, np.float32))
+    assert stats.decoded_mb == 3 * 224 * 224 * 3 / 1e6

@@ -310,7 +310,10 @@ class TestPipelineResilience:
         pipe = JpegVisionPipeline(patch=16, embed_dim=32, chunk_bits=256,
                                   backend="jnp", validate=True)
         tokens, stats = pipe.patches_for([b"junk", b"more junk"])
-        assert tokens.shape == (2, 0, 32)  # zero tokens, stream survives
+        # each quarantined image gives a zero crop, so zero tokens, and
+        # the stream survives
+        assert tokens.shape == (2, (224 // 16) ** 2, 32)
+        assert not np.asarray(tokens, np.float32).any()
         assert list(stats.status) == [STATUS_REJECTED, STATUS_REJECTED]
 
     def test_unvalidated_pipeline_reports_no_status(self):

@@ -205,3 +205,44 @@ def test_hlo_phases_reads_entry_level_op_names():
     ])
     assert api.hlo_phases(text) == {"while.3": api.SYNC_PHASE,
                                     "fusion.9": api.WRITE_PHASE}
+
+
+def _mixed(seed):
+    return [batch(seed)[0],
+            cr.encode_baseline(synth_image(40, 24, seed=seed + 5), quality=80,
+                               subsampling="4:4:4").jpeg_bytes]
+
+
+@pytest.mark.parametrize("emit,mixed", [("rgb", False), ("crop", False),
+                                        ("crop", True)],
+                         ids=["uniform-rgb", "uniform-crop", "mixed-crop"])
+def test_the_pixel_span_names_its_layout_crops_and_units(tmp_path, emit,
+                                                         mixed):
+    dec = ParallelDecoder.from_bytes(_mixed(50) if mixed else batch(50),
+                                     chunk_bits=256)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = dec.decode(emit=emit, out_size=(8, 8))
+        jax.block_until_ready(out.crop if emit == "crop" else out.rgb)
+    finally:
+        jax.profiler.stop_trace()
+    (args,) = [s[3] for s in program_spans(tmp_path)
+               if s[0] == "repro.dispatch.pixels"]
+    assert args["layout"] == ("mixed" if mixed else "uniform")
+    assert args["crops"] == (2 if emit == "crop" else 0)
+    assert args["units"] == dec.plan.total_units
+    assert args["units_cap"] == dec.shape.n_units
+
+
+def test_the_crop_program_maps_its_instructions_to_the_crop_scope():
+    """``pixel_phases()`` reads the crop scope from the cached trace: no
+    second trace, nothing before the first crop decode."""
+    api.clear_decode_programs()
+    dec = ParallelDecoder.from_bytes(_mixed(60), chunk_bits=256)
+    prog = dec.program
+    assert prog.pixel_phases() == {}
+    dec.decode(emit="crop", out_size=(8, 8)).crop.block_until_ready()
+    traces = prog.pixels_traces
+    phases = prog.pixel_phases()
+    assert prog.pixels_traces == traces
+    assert phases and set(phases.values()) == {api.CROP_PHASE}

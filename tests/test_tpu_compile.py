@@ -195,3 +195,39 @@ def test_huffman_kernel_still_refused(topo):
     from repro.kernels import backend as KB
     with pytest.raises(KB.KernelRefusedError, match="gather"):
         KB.check_pallas_compiles(topo.devices[0])
+
+
+def test_mixed_crop_program_compiles_at_ilsvrc_size(one_chip):
+    """The crop stage of a mixed batch (4:2:0, 4:4:4 and gray frames),
+    lowered at the ILSVRC-2012 training cell's size: 128 images, 999,015
+    data units of capacity, 224x224 crops. It compiles for v5e within a
+    quarter of the chip's memory, and its one gather, the taps', lies in
+    the crop scope."""
+    from conftest import synth_image
+    from repro.core import ParallelDecoder, api
+    from repro.core.bitstream import LAYOUT_COLS
+    from repro.jpeg import codec_ref as cr
+    blobs = [cr.encode_baseline(img, quality=90, subsampling=s).jpeg_bytes
+             for img, s in ((synth_image(37, 50, seed=1), "4:2:0"),
+                            (synth_image(50, 33, seed=2), "4:4:4"),
+                            (synth_image(30, 40, seed=3)[..., 0], "gray"))]
+    dec = ParallelDecoder.from_bytes(blobs, chunk_bits=1024)
+    assert not dec.plan.uniform
+    units, images = 999_015, 128
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,  # noqa: E731
+                                                    sharding=one_chip)
+    cropdev = {"m_matrices": sds(dec._pixdev["m_matrices"].shape, jnp.float32),
+               "unit_mrow": sds((units,), jnp.int32),
+               "layout": sds((images, LAYOUT_COLS), jnp.int32)}
+    compiled = dec.program.crop_fn.lower(
+        cropdev, sds((units, 64), jnp.int32), sds((images, 4), jnp.int32),
+        api.CROP_SIZE, None).compile()
+    mem = compiled.memory_analysis()
+    need = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert 0 < need < HBM_BYTES // 4
+    text = compiled.as_text()
+    assert api.CROP_PHASE in set(api.hlo_phases(text, (api.CROP_PHASE,)).values())
+    gathers = [line for line in text.splitlines()
+               if re.search(r"[ )]gather\(", line.split(" = ", 1)[-1])]
+    assert len(gathers) == 1 and api.CROP_PHASE in gathers[0]
